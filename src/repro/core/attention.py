@@ -8,6 +8,7 @@ region — the TPU analogue of the paper's UVA on-demand fetch (DESIGN.md §2).
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional, Tuple
 
@@ -173,6 +174,21 @@ def sparse_decode_attention(q: jax.Array,
     ).reshape(b, H, hd)
 
 
+def _isolated(fn, *args):
+    """``fn(*args)`` fenced by optimization barriers on both sides.
+
+    The decode engines hand the attention math operands from different
+    producers — pool gathers, the host-fetch hit/miss blend, gathers
+    hoisted by the overlapped pipeline. Left alone, XLA fuses each
+    producer into the contractions differently per program, and the TPU
+    then tiles and sums them differently: the resident and offloaded
+    engines' tokens drift apart after a few steps. Behind the barriers
+    every program compiles this math from the same isolated graph.
+    """
+    out = fn(*jax.lax.optimization_barrier(args))
+    return jax.lax.optimization_barrier(out)
+
+
 def dense_segment_scores(qg: jax.Array, k_sink: jax.Array,
                          k_loc: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Raw (unmasked, unscaled) sink/window score einsums.
@@ -180,15 +196,55 @@ def dense_segment_scores(qg: jax.Array, k_sink: jax.Array,
     Split out of ``_segment_attention`` so the overlapped fetch pipeline
     (ISSUE 9) can run these two einsums while the host K/V fetch is in
     flight — they depend only on staging-resident keys. Both the hoisted
-    and the inline path call this exact function, so the scores are
-    bit-identical regardless of where they were scheduled.
+    and the inline path call this exact function (behind ``_isolated``),
+    so the scores are bit-identical regardless of where they were
+    scheduled.
 
     qg: (b, G, Hg, hd) float32 → s_sink (b, G, Hg, sink), s_loc
     (b, G, Hg, W).
     """
-    s_sink = jnp.einsum("bghd,bsgd->bghs", qg, k_sink.astype(jnp.float32))
-    s_loc = jnp.einsum("bghd,bwgd->bghw", qg, k_loc.astype(jnp.float32))
-    return s_sink, s_loc
+    def scores(qg, k_sink, k_loc):
+        s_sink = jnp.einsum("bghd,bsgd->bghs", qg, k_sink.astype(jnp.float32))
+        s_loc = jnp.einsum("bghd,bwgd->bghw", qg, k_loc.astype(jnp.float32))
+        return s_sink, s_loc
+
+    return _isolated(scores, qg, k_sink, k_loc)
+
+
+def _joint_softmax(qg, k_ret, v_sink, v_ret, v_loc, s_sink, s_loc,
+                   sink_valid, ret_valid, loc_valid, *, sm_scale: float,
+                   softcap: float) -> jax.Array:
+    """The float math of ``_segment_attention``: retrieved scores, joint
+    masked softmax, value mix.
+
+    Each query head attends to its own k winners, but scoring them per
+    head is a batched mat-vec, which the TPU backend lowers to a VPU
+    multiply-reduce whose summation order follows operand layouts that
+    the barriers do not pin. So every query head of a kv head scores the
+    union of the group's Hg·k winners in one MXU contraction — the form
+    of the sink/window segments — and is masked to its own k: the other
+    heads' winners get exactly zero probability.
+    """
+    b, G, Hg, k_sz, hd = k_ret.shape
+    k_ret = k_ret.reshape(b, G, Hg * k_sz, hd)
+    v_ret = v_ret.reshape(b, G, Hg * k_sz, hd)
+    s_ret = jnp.einsum("bghd,bgnd->bghn", qg, k_ret.astype(jnp.float32))
+    own = (jnp.arange(Hg * k_sz) // k_sz)[None, :] == jnp.arange(Hg)[:, None]
+    ret_valid = own & ret_valid.reshape(b, G, 1, Hg * k_sz)
+    s_ret = jnp.where(ret_valid, s_ret, NEG_INF)
+    s_sink = jnp.where(sink_valid[:, None, None, :], s_sink, NEG_INF)
+    s_loc = jnp.where(loc_valid[:, None, None, :], s_loc, NEG_INF)
+
+    scores = jnp.concatenate([s_sink, s_ret, s_loc], axis=-1) * sm_scale
+    scores = _softcap(scores, softcap)
+    p = jax.nn.softmax(scores, axis=-1)
+    sink_size = s_sink.shape[-1]
+    p_sink, p_ret, p_loc = jnp.split(p, [sink_size, sink_size + Hg * k_sz],
+                                     axis=-1)
+    out = jnp.einsum("bghs,bsgd->bghd", p_sink, v_sink.astype(jnp.float32))
+    out += jnp.einsum("bghn,bgnd->bghd", p_ret, v_ret.astype(jnp.float32))
+    out += jnp.einsum("bghw,bwgd->bghd", p_loc, v_loc.astype(jnp.float32))
+    return out
 
 
 def _segment_attention(qg: jax.Array,
@@ -211,7 +267,8 @@ def _segment_attention(qg: jax.Array,
     probability; pools hold only zeros/real activations, never NaN).
 
     qg: (b, G, Hg, hd) float32; k_sink/v_sink: (b, sink, G, hd);
-    k_ret/v_ret: (b, G, Hg, k, hd); k_loc/v_loc: (b, W, G, hd).
+    k_ret/v_ret: (b, G, Hg, k, hd) — head h's k winners; k_loc/v_loc:
+    (b, W, G, hd).
     ``s_sink``/``s_loc`` may arrive precomputed (see
     ``dense_segment_scores``); masking always happens here.
     ``ret_keep`` (b, G, Hg, k) bool, optional: extra validity on the
@@ -221,40 +278,23 @@ def _segment_attention(qg: jax.Array,
     to zeroed garbage. All-True (or None) is bit-identical.
     → (b, G, Hg, hd) float32.
     """
-    # --- retrieved segment ------------------------------------------------
-    s_ret = jnp.einsum("bghd,bghkd->bghk", qg, k_ret.astype(jnp.float32))
     # guard: only positions actually inside the Retrieval region count —
     # with an empty region (early decode) Stage-II returns arbitrary indices
     ret_valid = (top_idx >= sink_size) & (top_idx < enc_end[:, None, None, None])
     if ret_keep is not None:
         ret_valid = ret_valid & ret_keep
-    s_ret = jnp.where(ret_valid, s_ret, NEG_INF)
+    sink_valid = jnp.arange(sink_size)[None] <= pos[:, None]    # (b, sink)
+    # local + update-buffer window
+    w_pos = window_start[:, None] + jnp.arange(window_size)     # (b, W)
+    loc_valid = ((w_pos >= enc_end[:, None]) & (w_pos >= sink_size)
+                 & (w_pos <= pos[:, None]))
 
     if s_sink is None:
         s_sink, s_loc = dense_segment_scores(qg, k_sink, k_loc)
-
-    # --- sink segment -----------------------------------------------------
-    v_sink = v_sink.astype(jnp.float32)
-    sink_valid = (jnp.arange(sink_size)[None] <= pos[:, None])  # (b, sink)
-    s_sink = jnp.where(sink_valid[:, None, None, :], s_sink, NEG_INF)
-
-    # --- local + update-buffer window --------------------------------------
-    v_loc = v_loc.astype(jnp.float32)
-    w_pos = window_start[:, None] + jnp.arange(window_size)  # (b, W)
-    loc_valid = ((w_pos >= enc_end[:, None]) & (w_pos >= sink_size)
-                 & (w_pos <= pos[:, None]))
-    s_loc = jnp.where(loc_valid[:, None, None, :], s_loc, NEG_INF)
-
-    # --- joint softmax -------------------------------------------------------
-    scores = jnp.concatenate([s_sink, s_ret, s_loc], axis=-1) * sm_scale
-    scores = _softcap(scores, softcap)
-    p = jax.nn.softmax(scores, axis=-1)
-    k_sz = top_idx.shape[-1]
-    p_sink, p_ret, p_loc = jnp.split(p, [sink_size, sink_size + k_sz], axis=-1)
-    out = jnp.einsum("bghs,bsgd->bghd", p_sink, v_sink)
-    out += jnp.einsum("bghk,bghkd->bghd", p_ret, v_ret.astype(jnp.float32))
-    out += jnp.einsum("bghw,bwgd->bghd", p_loc, v_loc)
-    return out
+    return _isolated(
+        functools.partial(_joint_softmax, sm_scale=sm_scale, softcap=softcap),
+        qg, k_ret, v_sink, v_ret, v_loc, s_sink, s_loc, sink_valid,
+        ret_valid, loc_valid)
 
 
 def sparse_decode_attention_paged(q: jax.Array, pool_k: jax.Array,

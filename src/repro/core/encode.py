@@ -35,10 +35,36 @@ class QueryTransform(NamedTuple):
     q_sub: jax.Array   # (..., B, m) rotated subspace components q̃_b
 
 
+def _sum_last(x: jax.Array) -> jax.Array:
+    """Sum over the last axis as a fixed tree of elementwise halvings.
+
+    A ``reduce`` sums in an order that the backend derives from the
+    operand's shape and layout, which XLA picks per program: a batched
+    row and the same row alone, or one device and a head-sharded mesh,
+    then round the same keys differently, and a one-ulp change of a norm
+    reorders near-tied candidates. Adding halves elementwise fixes the
+    order in every program. Zero-pads to a power of two (exact).
+    """
+    n = x.shape[-1]
+    p = 1 << (n - 1).bit_length()
+    if p != n:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, p - n)])
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    return x[..., 0]
+
+
+def _norm(x: jax.Array) -> jax.Array:
+    """‖x‖₂ over the last axis in float32, summed by ``_sum_last``."""
+    x = x.astype(jnp.float32)
+    return jnp.sqrt(_sum_last(x * x))
+
+
 def rotate_split(x: jax.Array, cfg: ParisKVConfig, signs: jax.Array) -> jax.Array:
     """normalize → SRHT rotate → split into (..., B, m) subspaces."""
     d = x.shape[-1]
-    norm = jnp.linalg.norm(x.astype(jnp.float32), axis=-1, keepdims=True)
+    norm = _norm(x)[..., None]
     x_hat = x.astype(jnp.float32) / jnp.maximum(norm, _EPS)
     x_rot = srht.srht_rotate(x_hat, signs)
     dp = x_rot.shape[-1]
@@ -47,11 +73,11 @@ def rotate_split(x: jax.Array, cfg: ParisKVConfig, signs: jax.Array) -> jax.Arra
 
 def encode_keys(keys: jax.Array, cfg: ParisKVConfig, signs: jax.Array) -> KeyMetadata:
     """Summarize raw keys (..., n, D) into retrieval metadata (A.2 + A.3)."""
-    norm = jnp.linalg.norm(keys.astype(jnp.float32), axis=-1)  # (..., n)
+    norm = _norm(keys)                                          # (..., n)
     sub = rotate_split(keys, cfg, signs)                        # (..., n, B, m)
 
     # polar decomposition per subspace
-    r = jnp.linalg.norm(sub, axis=-1)                           # (..., n, B)
+    r = _norm(sub)                                              # (..., n, B)
     u = sub / jnp.maximum(r[..., None], _EPS)                   # unit directions
 
     ids = centroids.assign(u)                                   # (..., n, B) uint8
@@ -59,7 +85,7 @@ def encode_keys(keys: jax.Array, cfg: ParisKVConfig, signs: jax.Array) -> KeyMet
 
     # alignment factor α = ⟨v, u⟩ (Eq. 7) and weight w = ‖k‖ r / α (Eq. 9)
     v = quantizer.decode_directions(codes, cfg.m, cfg.magnitude_bits)
-    alpha = jnp.sum(v * u, axis=-1)                             # (..., n, B)
+    alpha = _sum_last(v * u)                                    # (..., n, B)
     alpha = jnp.maximum(alpha, 1e-4)  # v shares u's signs ⇒ α > 0; guard anyway
     weights = norm[..., None] * r / alpha
     return KeyMetadata(ids, codes, weights.astype(jnp.float32))
@@ -67,7 +93,7 @@ def encode_keys(keys: jax.Array, cfg: ParisKVConfig, signs: jax.Array) -> KeyMet
 
 def encode_query(q: jax.Array, cfg: ParisKVConfig, signs: jax.Array) -> QueryTransform:
     """Transform an online query (..., D) identically to the keys."""
-    q_norm = jnp.linalg.norm(q.astype(jnp.float32), axis=-1)
+    q_norm = _norm(q)
     q_sub = rotate_split(q, cfg, signs)
     return QueryTransform(q_norm, q_sub)
 

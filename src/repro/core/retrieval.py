@@ -44,12 +44,15 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import centroids
 from repro.core.config import ParisKVConfig
 from repro.core.encode import KeyMetadata, QueryTransform
 
-NEG_INF = jnp.float32(-1e30)
+# a numpy scalar: a jnp one would initialise JAX's backends (and take the
+# chip) in every process that merely imports this module
+NEG_INF = np.float32(-1e30)
 
 
 class RetrievalResult(NamedTuple):
@@ -226,7 +229,8 @@ def rerank(meta: KeyMetadata, qt: QueryTransform, cand_idx: jax.Array,
     codes = jnp.take_along_axis(codes_b, cand_idx[..., None], axis=-2)   # (..., C, B)
     w = jnp.take_along_axis(w_b, cand_idx[..., None], axis=-2)           # (..., C, B)
     v = quantizer.decode_directions(codes, cfg.m, cfg.magnitude_bits)    # (..., C, B, m)
-    dots = jnp.einsum("...cbm,...bm->...cb", v, qt.q_sub)
+    dots = jnp.einsum("...cbm,...bm->...cb", v, qt.q_sub,
+                      precision=jax.lax.Precision.HIGHEST)
     est = qt.q_norm[..., None] * jnp.sum(w * dots, axis=-1)
 
     valid_b = jnp.broadcast_to(valid, cand_idx.shape[:-1] + valid.shape[-1:])
@@ -390,7 +394,8 @@ def rerank_paged(pool_codes: jax.Array, pool_w: jax.Array,
     codes = gather_meta_heads_physical(pool_codes, phys_rows)
     w = gather_meta_heads_physical(pool_w, phys_rows)
     v = quantizer.decode_directions(codes, cfg.m, cfg.magnitude_bits)
-    dots = jnp.einsum("...cbm,...bm->...cb", v, qt.q_sub)
+    dots = jnp.einsum("...cbm,...bm->...cb", v, qt.q_sub,
+                      precision=jax.lax.Precision.HIGHEST)
     est = qt.q_norm[..., None] * jnp.sum(w * dots, axis=-1)
     cand_valid = ((cand_idx >= cfg.sink_size)
                   & (cand_idx < enc_end[:, None, None, None]))
@@ -413,21 +418,13 @@ def _rerank_paged_kernel_batched(pool, phys_rows: jax.Array,
                                  cand_idx: jax.Array, qt: QueryTransform,
                                  enc_end: jax.Array,
                                  cfg: ParisKVConfig) -> jax.Array:
-    """Stage II via the Pallas kernel (kernels.rerank.rerank_paged_kernel),
-    vmapped over batch rows and query heads onto the kernel's per-(G, C)
-    contract; the invalid-candidate mask matches ``rerank_paged``."""
+    """Stage II via the Pallas kernel (kernels.rerank.rerank_paged_kernel);
+    the invalid-candidate mask matches ``rerank_paged``."""
     from repro.kernels.rerank import rerank_paged_kernel
 
-    def one(phys_g, qsub, qnorm):
-        return rerank_paged_kernel(pool.meta_codes, pool.meta_w, phys_g,
-                                   qsub, qnorm, m=cfg.m,
-                                   bits=cfg.magnitude_bits)
-
-    fn = jax.vmap(jax.vmap(one))                     # leading (b, Hg)
-    est = fn(jnp.moveaxis(phys_rows, 2, 1),          # (b, Hg, G, C)
-             jnp.moveaxis(qt.q_sub, 2, 1),           # (b, Hg, G, B, m)
-             jnp.moveaxis(qt.q_norm, 2, 1))          # (b, Hg, G)
-    est = jnp.moveaxis(est, 1, 2)                    # (b, G, Hg, C)
+    est = rerank_paged_kernel(pool.meta_codes, pool.meta_w, phys_rows,
+                              qt.q_sub, qt.q_norm, m=cfg.m,
+                              bits=cfg.magnitude_bits)
     cand_valid = ((cand_idx >= cfg.sink_size)
                   & (cand_idx < enc_end[:, None, None, None]))
     return jnp.where(cand_valid, est, NEG_INF)

@@ -20,19 +20,11 @@ only where no TPU is attached. Every kernel entry point takes
      assert-compile in CI images that advertise a TPU;
   3. else autodetect: compile on TPU, interpret everywhere else.
 
-The old module constant ``INTERPRET`` is kept for callers/tests that want
-the raw autodetect answer without the env override.
+The backend is asked for on the first call, never at import: importing
+this package must not initialise JAX's backends (that would take the
+chip in every process that merely imports the kernels).
 """
 import os
-
-IS_TPU = False
-try:  # pragma: no cover
-    import jax
-    IS_TPU = jax.default_backend() == "tpu"
-except Exception:
-    pass
-
-INTERPRET = not IS_TPU
 
 
 def resolve_interpret(interpret=None) -> bool:
@@ -42,4 +34,5 @@ def resolve_interpret(interpret=None) -> bool:
     env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip()
     if env:                       # empty/unset → autodetect
         return env.lower() not in ("0", "false")
-    return INTERPRET
+    import jax
+    return jax.default_backend() != "tpu"

@@ -23,10 +23,10 @@ from repro.kernels import resolve_interpret
 
 
 def _kernel(scores_ref, hist_ref, *, score_range: int):
-    s = scores_ref[...].astype(jnp.int32)          # (bn,)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (s.shape[0], score_range), 1)
-    onehot = (s[:, None] == iota).astype(jnp.int32)
-    hist_ref[...] = onehot.sum(axis=0)[None, :]
+    s = scores_ref[...].astype(jnp.int32)          # (1, bn)
+    iota = jax.lax.broadcasted_iota(jnp.int32, (score_range, s.shape[1]), 0)
+    onehot = (s == iota).astype(jnp.int32)         # (range, bn)
+    hist_ref[0] = onehot.sum(axis=1, keepdims=True)
 
 
 def histogram_pallas(scores: jax.Array, *, score_range: int,
@@ -47,12 +47,15 @@ def _histogram_pallas(scores, *, score_range: int, block_n: int,
     n = scores.shape[0]
     assert n % block_n == 0
     grid = (n // block_n,)
+    # scores as one (1, n) row and partial histograms as (range, 1)
+    # columns: every block's last two dims are then legal TPU tiles
     partial = pl.pallas_call(
         functools.partial(_kernel, score_range=score_range),
         grid=grid,
-        in_specs=[pl.BlockSpec((block_n,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1, score_range), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // block_n, score_range), jnp.int32),
+        in_specs=[pl.BlockSpec((1, block_n), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((1, score_range, 1), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // block_n, score_range, 1),
+                                       jnp.int32),
         interpret=interpret,
-    )(scores)
-    return partial.sum(0)
+    )(scores.reshape(1, n))
+    return partial.sum((0, 2))

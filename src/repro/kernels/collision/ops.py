@@ -1,5 +1,5 @@
-"""Jitted wrappers: batch via vmap, interpret-mode autodetect (see
-repro.kernels.resolve_interpret)."""
+"""Batched wrappers over the collision kernel, interpret-mode autodetect
+(see repro.kernels.resolve_interpret)."""
 from __future__ import annotations
 
 import jax
@@ -15,14 +15,7 @@ def collision_scores_kernel(ids: jax.Array, table: jax.Array,
 
     Tail padding to the block multiple happens inside collision_pallas.
     """
-    lead = ids.shape[:-2]
-    n, B = ids.shape[-2], ids.shape[-1]
-    flat_ids = ids.reshape((-1, n, B))
-    flat_tbl = jnp.broadcast_to(table, lead + table.shape[-2:]).reshape(
-        (-1,) + table.shape[-2:])
-    fn = lambda i, t: collision_pallas(i, t, block_n=block_n)
-    out = jax.vmap(fn)(flat_ids, flat_tbl)
-    return out.reshape(lead + (n,))
+    return collision_pallas(ids, table, block_n=block_n)
 
 
 def collision_scores_paged_kernel(pool_ids: jax.Array,
@@ -44,8 +37,7 @@ def collision_scores_paged_kernel(pool_ids: jax.Array,
     nb, _, bs, _ = pool_ids.shape
     b, nblk = block_tables.shape
     safe_bt = jnp.clip(block_tables, 0, nb - 1).astype(jnp.int32)
-    fn = lambda bt, t: collision_paged_pallas(bt, pool_ids, t)
-    scores = jax.vmap(fn)(safe_bt, tables)            # (b, G, Hg, n)
+    scores = collision_paged_pallas(safe_bt, pool_ids, tables)  # (b,G,Hg,n)
     pos = jnp.arange(nblk * bs)
     valid = (pos[None] >= sink_size) & (pos[None] < enc_end[:, None])
     return jnp.where(valid[:, None, None, :], scores, -1)

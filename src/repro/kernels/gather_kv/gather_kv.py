@@ -19,8 +19,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import resolve_interpret
 
 
-def _kernel(idx_ref, rows_ref, out_ref):
-    out_ref[...] = rows_ref[...]
+def _kernel(*refs):
+    rows_ref, out_ref = refs[-2:]        # after the scalar-prefetch refs
+    out_ref[...] = rows_ref[...].reshape(out_ref.shape)
 
 
 def gather_rows_pallas(store: jax.Array, idx: jax.Array, *,
@@ -37,22 +38,21 @@ def gather_rows_pallas(store: jax.Array, idx: jax.Array, *,
 def _gather_rows_pallas(store, idx, *, interpret: bool):
     n, d = store.shape
     k = idx.shape[0]
+    # rows as (1, d) tiles of an (n, 1, d) view: a block's last two dims
+    # then equal the array's, as the TPU lowering requires
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(k,),
-        in_specs=[pl.BlockSpec((1, d), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),
+        in_specs=[pl.BlockSpec((1, 1, d),
+                               lambda i, idx_ref: (idx_ref[i], 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, d), lambda i, idx_ref: (i, 0, 0)),
     )
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k, d), store.dtype),
+        out_shape=jax.ShapeDtypeStruct((k, 1, d), store.dtype),
         interpret=interpret,
-    )(idx, store)
-
-
-def _paged_kernel(idx_ref, bt_ref, rows_ref, out_ref):
-    out_ref[...] = rows_ref[0]          # (1, 1, d) block → (1, d) out row
+    )(idx, store.reshape(n, 1, d)).reshape(k, d)
 
 
 def gather_rows_paged_pallas(pool: jax.Array, block_table: jax.Array,
@@ -65,7 +65,7 @@ def gather_rows_paged_pallas(pool: jax.Array, block_table: jax.Array,
     token positions → (k, d). Both the index vector and the block table
     ride in SMEM via scalar prefetch; the input index_map double-dereferences
     ``block_table[idx[i] // block_size]`` so each grid step DMAs exactly
-    one (1, 1, d) physical row HBM→VMEM — the paged UVA fetch.
+    one (1, d) physical row HBM→VMEM — the paged UVA fetch.
     """
     return _gather_rows_paged_pallas(pool, block_table, idx,
                                      interpret=resolve_interpret(interpret))
@@ -79,14 +79,16 @@ def _gather_rows_paged_pallas(pool, block_table, idx, *, interpret: bool):
         num_scalar_prefetch=2,
         grid=(k,),
         in_specs=[pl.BlockSpec(
-            (1, 1, d),
+            (1, 1, 1, d),
             lambda i, idx_ref, bt_ref: (bt_ref[idx_ref[i] // block_size],
-                                        idx_ref[i] % block_size, 0))],
-        out_specs=pl.BlockSpec((1, d), lambda i, idx_ref, bt_ref: (i, 0)),
+                                        idx_ref[i] % block_size, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, d),
+                               lambda i, idx_ref, bt_ref: (i, 0, 0)),
     )
     return pl.pallas_call(
-        _paged_kernel,
+        _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k, d), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((k, 1, d), pool.dtype),
         interpret=interpret,
-    )(idx, block_table, pool)
+    )(idx, block_table,
+      pool.reshape(num_blocks, block_size, 1, d)).reshape(k, d)

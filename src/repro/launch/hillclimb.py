@@ -111,7 +111,6 @@ def one_layer_decode_shardmap(cfg, pcfg: ParisKVConfig, mesh, batch, n,
     psum. Collectives: O(k·shards·4B) gather + O(b·G·Hg·k·hd) psum — vs the
     baseline's cache-scale all-gathers.
     """
-    from jax.experimental.shard_map import shard_map
     G, H, hd = cfg.num_kv_heads, cfg.num_heads, cfg.head_dim
     Hg = H // G
     Bsub = pcfg.num_subspaces(hd)
@@ -167,8 +166,8 @@ def one_layer_decode_shardmap(cfg, pcfg: ParisKVConfig, mesh, batch, n,
                 P(batch_axes, None, seq_axes, None),
                 P(batch_axes, None, seq_axes, None))
     out_spec = P(batch_axes, None, None, None)
-    fn = shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_spec, check_vma=False)
     with mesh:
         lowered = jax.jit(fn).lower(*(sp[k] for k in (
             "q", "k_cache", "v_cache", "ids", "codes", "w")))

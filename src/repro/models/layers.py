@@ -30,24 +30,11 @@ from repro.core.config import ParisKVConfig
 
 # ----------------------------------------------------------------- helpers --
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: the top-level ``jax.shard_map``
-    (``check_vma``) when this jax has it, ``jax.experimental.shard_map``
-    (``check_rep``) otherwise. Replication checking is disabled either
-    way — the serving specs mark replicated outputs that the checker's
-    static analysis cannot prove (e.g. values equalized by all_gather)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            pass
-    from jax.experimental.shard_map import shard_map as esm
-    try:
-        return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-    except TypeError:
-        return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with replication checking disabled — the serving
+    specs mark replicated outputs that the checker's static analysis
+    cannot prove (e.g. values equalized by all_gather)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def truncated_normal(key, shape, std=0.02, dtype=jnp.float32):

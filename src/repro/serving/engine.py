@@ -507,8 +507,19 @@ class ServingEngine:
             except Exception as exc:  # noqa: BLE001 — quarantine boundary
                 self._quarantine(slot, req, exc)
 
+    def chunk_args(self) -> tuple:
+        """The arguments of the next decode chunk: params, the live
+        SlotState (donated by the chunk) and, for the paged engines, the
+        block tables (+ the staging map when offloaded). Needs start()."""
+        return (self.params, self._state)
+
+    def lower_chunk(self):
+        """Lower the next decode chunk for inspection — ``.compile()``
+        gives the program the device runs. Needs start()."""
+        return self._chunk.lower(*self.chunk_args())
+
     def _run_chunk(self):
-        tokens, self._state = self._chunk(self.params, self._state)
+        tokens, self._state = self._chunk(*self.chunk_args())
         return np.asarray(tokens), np.asarray(self._state.remaining)
 
     def _release_slot(self, slot: int) -> None:
@@ -735,7 +746,12 @@ class PagedServingEngine(ServingEngine):
         if mesh_shards > 1:
             P_ = jax.sharding.PartitionSpec
             rep = P_()
-            self.mesh = jax.make_mesh((mesh_shards,), ("kv",))
+            # Auto axes: the specs below place state by shard_map, and
+            # values computed outside it (solo-prefill results) stay
+            # replicated — Explicit axes would type-check them apart
+            self.mesh = jax.make_mesh(
+                (mesh_shards,), ("kv",),
+                axis_types=(jax.sharding.AxisType.Auto,))
             dist = SV.ShardedPagedDist("kv", mesh_shards)
             ss = SV.sharded_state_specs(
                 SV.make_paged_caches(cfg, max_batch, self.num_blocks,
@@ -1026,10 +1042,8 @@ class PagedServingEngine(ServingEngine):
         super()._pre_chunk_slot(slot, req)     # engine.slot fault hook
         self._ensure_blocks(slot)
 
-    def _run_chunk(self):
-        tokens, self._state = self._chunk(self.params, self._state,
-                                          jnp.asarray(self._bt))
-        return np.asarray(tokens), np.asarray(self._state.remaining)
+    def chunk_args(self) -> tuple:
+        return (self.params, self._state, jnp.asarray(self._bt))
 
     def _after_collect(self, slot: int, req: Request) -> None:
         # host view of the device pos: last prompt token + decoded tokens
@@ -1654,12 +1668,11 @@ class OffloadedPagedServingEngine(PagedServingEngine):
         super()._pre_chunk()              # lazy block allocation first
         self._update_staging()
 
+    def chunk_args(self) -> tuple:
+        return super().chunk_args() + (jnp.asarray(self.staging.dev_map),)
+
     def _run_chunk(self):
-        tokens, self._state = self._chunk(
-            self.params, self._state, jnp.asarray(self._bt),
-            jnp.asarray(self.staging.dev_map))
-        toks = np.asarray(tokens)
-        rem = np.asarray(self._state.remaining)
+        toks, rem = super()._run_chunk()
         self._harvest_fetch_stats()
         return toks, rem
 

@@ -23,6 +23,25 @@ jax.config.update("jax_enable_x64", False)
 CFG = ParisKVConfig()
 
 
+# ------------------------------------------------------- encode sums ----
+@pytest.mark.parametrize("n", [1, 8, 64, 100, 128])
+def test_sum_last_is_a_fixed_halving_tree(n):
+    """The encode norms sum as elementwise halvings, zero-padded to a
+    power of two — the same order whatever shape or layout the operand
+    has, and equal to a plain sum up to f32 rounding."""
+    from repro.core.encode import _norm, _sum_last
+    x = np.random.RandomState(n).randn(3, n).astype(np.float32)
+    want = np.pad(x, ((0, 0), (0, (1 << (n - 1).bit_length()) - n)))
+    while want.shape[-1] > 1:
+        h = want.shape[-1] // 2
+        want = want[:, :h] + want[:, h:]
+    got = np.asarray(jax.jit(_sum_last)(jnp.asarray(x)))
+    np.testing.assert_array_equal(got, want[:, 0])
+    np.testing.assert_allclose(got, x.sum(-1), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(_norm(jnp.asarray(x))),
+                               np.linalg.norm(x, axis=-1), rtol=1e-6)
+
+
 # ---------------------------------------------------------------- SRHT ----
 @pytest.mark.parametrize("d", [64, 80, 128, 240, 256, 576, 1024])
 def test_srht_orthogonal_preserves_ip(d):

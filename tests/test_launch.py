@@ -2,6 +2,7 @@
 parser, roofline math — all single-device safe (no 512-device flags)."""
 import jax
 import numpy as np
+import pytest
 
 from repro.launch import mesh as MX
 
@@ -100,3 +101,75 @@ def test_model_flops_per_device():
     f_dec = model_flops_per_device("qwen2-1.5b", "decode_32k", 256)
     assert f_train > f_dec * 1000     # train crunches ~1M tokens vs 128
     assert f_dec > 0
+
+
+@pytest.mark.parametrize("kind,kw,want", [
+    ("slots", {}, "ServingEngine"),
+    ("wave", {}, "WaveServingEngine"),
+    ("paged", {}, "PagedServingEngine"),
+    ("paged", {"offload": True}, "OffloadedPagedServingEngine"),
+])
+def test_build_engine_constructs_each_engine(kind, kw, want):
+    """The launcher's one constructor (chip_smoke.py builds through it)."""
+    from repro import configs
+    from repro.launch.serve import build_engine
+    cfg = configs.smoke("qwen2-1.5b")
+    eng = build_engine(cfg, None, engine=kind, n_max=256, max_batch=2,
+                       block_size=64, prefill_budget=32 if kind != "wave"
+                       else 0, **kw)
+    try:
+        assert type(eng).__name__ == want
+    finally:
+        if hasattr(eng, "close"):
+            eng.close()
+
+
+def test_build_engine_rejects_paged_only_settings():
+    from repro import configs
+    from repro.launch.serve import build_engine
+    cfg = configs.smoke("qwen2-1.5b")
+    with pytest.raises(ValueError):
+        build_engine(cfg, None, engine="slots", offload=True)
+    with pytest.raises(ValueError):
+        build_engine(cfg, None, engine="paged", use_pariskv=False)
+
+
+def test_compile_cache_dir_env_then_repo(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache is <repo>/.jax_cache."""
+    from pathlib import Path
+    from repro.launch.serve import enable_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = enable_compile_cache()
+        assert Path(got) == Path(__file__).resolve().parents[1] / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_importing_the_package_initialises_no_backend():
+    """Importing any module of ``repro`` must not start a JAX backend: on
+    a TPU host that would take the chip in every process that merely
+    imports the code. Checked in a fresh interpreter, since this test
+    process has long since started its backend."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    src = Path(__file__).resolve().parents[1] / "src"
+    code = (
+        "import importlib, pkgutil, repro\n"
+        "from jax._src import xla_bridge as xb\n"
+        "for m in pkgutil.walk_packages(repro.__path__, 'repro.'):\n"
+        "    importlib.import_module(m.name)\n"
+        "    assert not xb._backends, m.name\n")
+    env = dict(os.environ, PYTHONPATH=str(src),
+               JAX_PLATFORMS="cpu")
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=240)
+    assert res.returncode == 0, res.stderr[-2000:]

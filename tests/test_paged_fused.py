@@ -240,9 +240,10 @@ def test_rerank_paged_kernel_matches_ref():
     q = jax.random.normal(jax.random.PRNGKey(6), (1, G, D))
     qt = encode_query(q, CFG, SIGNS)
 
-    got = rerank_paged_kernel(pool.meta_codes, pool.meta_w, phys,
-                              qt.q_sub[0], qt.q_norm[0], m=CFG.m,
-                              block_c=32)
+    got = rerank_paged_kernel(pool.meta_codes, pool.meta_w,
+                              phys[None, :, None], qt.q_sub[:, :, None],
+                              qt.q_norm[:, :, None], m=CFG.m,
+                              block_c=32)[0, :, 0]
     ids_v, codes_v, w_v = paged_meta_view(pool, btj)
     want = rerank_ref(codes_v[0][:, lidx], w_v[0][:, lidx], qt.q_sub[0],
                       qt.q_norm[0][:, None], CFG.m)
@@ -271,7 +272,7 @@ def test_resolve_interpret_env_override(monkeypatch):
     from repro import kernels
 
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    assert kernels.resolve_interpret(None) == kernels.INTERPRET
+    assert kernels.resolve_interpret(None) == (jax.default_backend() != "tpu")
     assert kernels.resolve_interpret(True) is True
     assert kernels.resolve_interpret(False) is False
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
