@@ -18,7 +18,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import centroids, quantizer, srht
+from repro.core import centroids, phases, quantizer, srht
 from repro.core.config import ParisKVConfig
 
 _EPS = 1e-20
@@ -92,10 +92,12 @@ def encode_keys(keys: jax.Array, cfg: ParisKVConfig, signs: jax.Array) -> KeyMet
 
 
 def encode_query(q: jax.Array, cfg: ParisKVConfig, signs: jax.Array) -> QueryTransform:
-    """Transform an online query (..., D) identically to the keys."""
-    q_norm = _norm(q)
-    q_sub = rotate_split(q, cfg, signs)
-    return QueryTransform(q_norm, q_sub)
+    """Transform an online query (..., D) identically to the keys (the
+    first step of building the Stage-I tier table)."""
+    with jax.named_scope(phases.TIER_TABLE):
+        q_norm = _norm(q)
+        q_sub = rotate_split(q, cfg, signs)
+        return QueryTransform(q_norm, q_sub)
 
 
 def estimate_inner_products(meta: KeyMetadata, qt: QueryTransform,

@@ -1,0 +1,23 @@
+"""The decode step's phases, named on the device.
+
+Each name is a ``jax.named_scope``: every op traced under it carries the
+name in the ``op_name`` of its HLO metadata
+(``jit(<lambda>)/while/body/.../pariskv.select/...``), so the compiled
+program and a device trace can charge each op to a phase. The names are
+defined here and nowhere else.
+
+An op belongs to the **outermost** name on its path. The decode phases
+never nest in one another; everything the chunked prefill runs inside a
+mixed step sits under ``FILL``, whatever it calls.
+"""
+TIER_TABLE = "pariskv.tier_table"   # encode_query, centroid scores, tier weights
+STAGE1 = "pariskv.stage1"           # collision scoring (kernel or twin)
+SELECT = "pariskv.select"           # top-C candidates and their pool rows
+STAGE2 = "pariskv.stage2"           # rerank, mask, final top-k, winner rows
+ATTEND = "pariskv.attend"           # K/V append, winner gather, attention
+PROMOTE = "pariskv.promote"         # block encode + histogram update
+DENSE = "model.dense"               # embed, norms, projections, MLP, head
+FILL = "model.fill"                 # the chunked-prefill branch
+
+DECODE = (TIER_TABLE, STAGE1, SELECT, STAGE2, ATTEND, PROMOTE, DENSE)
+ALL = DECODE + (FILL,)

@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import centroids
+from repro.core import centroids, phases
 from repro.core.config import ParisKVConfig
 from repro.core.encode import KeyMetadata, QueryTransform
 
@@ -140,28 +140,30 @@ def collision_scores(meta_ids: jax.Array, q_sub: jax.Array, valid: jax.Array,
     """
     nb = cfg.num_centroids()
     n = meta_ids.shape[-2]
-    cs = centroids.centroid_scores(q_sub, cfg.m)                   # (..., B, 2^m)
-    stride = max(n // hist_sample, 1) if hist_sample else 1
-    if stride > 1:
-        counts = bucket_histogram(meta_ids[..., ::stride, :],
-                                  valid[..., ::stride], nb) * stride
-    else:
-        counts = bucket_histogram(meta_ids, valid, nb)             # (..., B, 2^m)
-    n_valid = jnp.sum(valid, axis=-1)
-    table = tier_weight_table(cs, counts, n_valid, cfg)            # (..., B, 2^m)
+    with jax.named_scope(phases.TIER_TABLE):
+        cs = centroids.centroid_scores(q_sub, cfg.m)               # (..., B, 2^m)
+        stride = max(n // hist_sample, 1) if hist_sample else 1
+        if stride > 1:
+            counts = bucket_histogram(meta_ids[..., ::stride, :],
+                                      valid[..., ::stride], nb) * stride
+        else:
+            counts = bucket_histogram(meta_ids, valid, nb)         # (..., B, 2^m)
+        n_valid = jnp.sum(valid, axis=-1)
+        table = tier_weight_table(cs, counts, n_valid, cfg)        # (..., B, 2^m)
 
     # per-key lookup S_i = Σ_b table[b, id_{i,b}] as ONE flat gather over
     # (B·2^m,) — avoids a (B, n) transpose copy + B separate gathers.
-    table_flat = table.reshape(table.shape[:-2] + (-1,))           # (..., B·2^m)
-    offsets = (jnp.arange(meta_ids.shape[-1], dtype=jnp.int32) * nb)
-    idx_flat = (meta_ids.astype(jnp.int32) + offsets).reshape(
-        meta_ids.shape[:-2] + (-1,))                               # (..., n·B)
-    idx_flat = jnp.broadcast_to(idx_flat,
-                                table_flat.shape[:-1] + idx_flat.shape[-1:])
-    per_key = jnp.take_along_axis(table_flat, idx_flat, axis=-1)
-    scores = per_key.reshape(per_key.shape[:-1] + (n, meta_ids.shape[-1])
-                             ).sum(-1)
-    return jnp.where(valid, scores, -1)
+    with jax.named_scope(phases.STAGE1):
+        table_flat = table.reshape(table.shape[:-2] + (-1,))       # (..., B·2^m)
+        offsets = (jnp.arange(meta_ids.shape[-1], dtype=jnp.int32) * nb)
+        idx_flat = (meta_ids.astype(jnp.int32) + offsets).reshape(
+            meta_ids.shape[:-2] + (-1,))                           # (..., n·B)
+        idx_flat = jnp.broadcast_to(
+            idx_flat, table_flat.shape[:-1] + idx_flat.shape[-1:])
+        per_key = jnp.take_along_axis(table_flat, idx_flat, axis=-1)
+        scores = per_key.reshape(per_key.shape[:-1] + (n, meta_ids.shape[-1])
+                                 ).sum(-1)
+        return jnp.where(valid, scores, -1)
 
 
 def select_candidates(scores: jax.Array, num_candidates: int) -> jax.Array:
@@ -250,14 +252,16 @@ def retrieve(meta: KeyMetadata, qt: QueryTransform, valid: jax.Array,
     coarse = collision_scores(meta.centroid_ids, qt.q_sub, valid, cfg,
                               hist_sample=hist_sample)
     B = meta.centroid_ids.shape[-1]
-    if bucket_select:
-        cand = select_candidates_bucket(coarse, num_candidates,
-                                        score_range=max(cfg.tier_weights) * B)
-    else:
-        cand = select_candidates(coarse, num_candidates)
-    est = rerank(meta, qt, cand, valid, cfg)
-    top_est, top_pos = jax.lax.top_k(est, top_k)
-    top_idx = jnp.take_along_axis(cand, top_pos, axis=-1)
+    with jax.named_scope(phases.SELECT):
+        if bucket_select:
+            cand = select_candidates_bucket(
+                coarse, num_candidates, score_range=max(cfg.tier_weights) * B)
+        else:
+            cand = select_candidates(coarse, num_candidates)
+    with jax.named_scope(phases.STAGE2):
+        est = rerank(meta, qt, cand, valid, cfg)
+        top_est, top_pos = jax.lax.top_k(est, top_k)
+        top_idx = jnp.take_along_axis(cand, top_pos, axis=-1)
     return RetrievalResult(top_idx, top_est, cand, coarse)
 
 
@@ -287,8 +291,9 @@ def retrieve_paged(meta: KeyMetadata, qt: QueryTransform, valid: jax.Array,
     # unallocated entries (< 0) are clipped to block 0 (_block_relative) —
     # such hits only arise at masked (invalid) positions, which attention
     # re-masks by enc_end; allocated entries are in-bounds by construction
-    safe_blk, off, phys_rows = _block_relative(res.indices, block_tables,
-                                               block_size)
+    with jax.named_scope(phases.STAGE2):
+        safe_blk, off, phys_rows = _block_relative(res.indices, block_tables,
+                                                   block_size)
     return PagedRetrievalResult(
         indices=res.indices, block_ids=safe_blk, offsets=off,
         phys_rows=phys_rows, scores=res.scores,
@@ -355,23 +360,26 @@ def collision_scores_paged(pool_ids: jax.Array, block_tables: jax.Array,
     b, nblk = block_tables.shape
     n = nblk * bs
     nc = cfg.num_centroids()
-    cs = centroids.centroid_scores(q_sub, cfg.m)           # (b, G, Hg, B, 2^m)
-    n_valid = jnp.maximum(enc_end - cfg.sink_size, 0)      # (b,)
-    table = tier_weight_table(cs, counts[:, :, None],
-                              n_valid[:, None, None], cfg)
-    safe = jnp.clip(block_tables, 0, nb - 1)
-    ids = pool_ids[safe]                                   # (b, nblk, G, bs, B)
-    ids = jnp.moveaxis(ids, 2, 1).reshape(b, G, n, B)
-    # same flat (B·2^m) lookup as collision_scores
-    table_flat = table.reshape(table.shape[:-2] + (-1,))   # (b, G, Hg, B·2^m)
-    offsets = jnp.arange(B, dtype=jnp.int32) * nc
-    idx_flat = (ids.astype(jnp.int32) + offsets).reshape(b, G, 1, n * B)
-    idx_flat = jnp.broadcast_to(idx_flat, table_flat.shape[:-1] + (n * B,))
-    per_key = jnp.take_along_axis(table_flat, idx_flat, axis=-1)
-    scores = per_key.reshape(per_key.shape[:-1] + (n, B)).sum(-1)
-    pos = jnp.arange(n)
-    valid = (pos[None] >= cfg.sink_size) & (pos[None] < enc_end[:, None])
-    return jnp.where(valid[:, None, None, :], scores, -1)
+    with jax.named_scope(phases.TIER_TABLE):
+        cs = centroids.centroid_scores(q_sub, cfg.m)       # (b, G, Hg, B, 2^m)
+        n_valid = jnp.maximum(enc_end - cfg.sink_size, 0)  # (b,)
+        table = tier_weight_table(cs, counts[:, :, None],
+                                  n_valid[:, None, None], cfg)
+    with jax.named_scope(phases.STAGE1):
+        safe = jnp.clip(block_tables, 0, nb - 1)
+        ids = pool_ids[safe]                               # (b, nblk, G, bs, B)
+        ids = jnp.moveaxis(ids, 2, 1).reshape(b, G, n, B)
+        # same flat (B·2^m) lookup as collision_scores
+        table_flat = table.reshape(table.shape[:-2] + (-1,))  # (b,G,Hg,B·2^m)
+        offsets = jnp.arange(B, dtype=jnp.int32) * nc
+        idx_flat = (ids.astype(jnp.int32) + offsets).reshape(b, G, 1, n * B)
+        idx_flat = jnp.broadcast_to(idx_flat,
+                                    table_flat.shape[:-1] + (n * B,))
+        per_key = jnp.take_along_axis(table_flat, idx_flat, axis=-1)
+        scores = per_key.reshape(per_key.shape[:-1] + (n, B)).sum(-1)
+        pos = jnp.arange(n)
+        valid = (pos[None] >= cfg.sink_size) & (pos[None] < enc_end[:, None])
+        return jnp.where(valid[:, None, None, :], scores, -1)
 
 
 def rerank_paged(pool_codes: jax.Array, pool_w: jax.Array,
@@ -464,31 +472,34 @@ def retrieve_paged_fused(pool, block_tables: jax.Array, qt: QueryTransform,
         use_kernels = not resolve_interpret(None)
     if use_kernels:
         from repro.kernels.collision import collision_scores_paged_kernel
-        cs = centroids.centroid_scores(qt.q_sub, cfg.m)
-        n_valid = jnp.maximum(enc_end - cfg.sink_size, 0)
-        table = tier_weight_table(cs, counts[:, :, None],
-                                  n_valid[:, None, None], cfg)
-        coarse = collision_scores_paged_kernel(pool.meta_ids, block_tables,
-                                               table, enc_end,
-                                               cfg.sink_size)
+        with jax.named_scope(phases.TIER_TABLE):
+            cs = centroids.centroid_scores(qt.q_sub, cfg.m)
+            n_valid = jnp.maximum(enc_end - cfg.sink_size, 0)
+            table = tier_weight_table(cs, counts[:, :, None],
+                                      n_valid[:, None, None], cfg)
+        with jax.named_scope(phases.STAGE1):
+            coarse = collision_scores_paged_kernel(
+                pool.meta_ids, block_tables, table, enc_end, cfg.sink_size)
     else:
         coarse = collision_scores_paged(pool.meta_ids, block_tables,
                                         qt.q_sub, counts, enc_end, cfg)
-    if bucket_select:
-        cand = select_candidates_bucket(coarse, num_candidates,
-                                        score_range=max(cfg.tier_weights) * B)
-    else:
-        cand = select_candidates(coarse, num_candidates)
-    _, _, cand_phys = _block_relative(cand, block_tables, bs)
-    if use_kernels:
-        est = _rerank_paged_kernel_batched(pool, cand_phys, cand, qt,
-                                           enc_end, cfg)
-    else:
-        est = rerank_paged(pool.meta_codes, pool.meta_w, cand_phys, cand, qt,
-                           enc_end, cfg)
-    top_est, top_pos = jax.lax.top_k(est, top_k)
-    top_idx = jnp.take_along_axis(cand, top_pos, axis=-1)
-    safe_blk, off, phys_rows = _block_relative(top_idx, block_tables, bs)
+    with jax.named_scope(phases.SELECT):
+        if bucket_select:
+            cand = select_candidates_bucket(
+                coarse, num_candidates, score_range=max(cfg.tier_weights) * B)
+        else:
+            cand = select_candidates(coarse, num_candidates)
+        _, _, cand_phys = _block_relative(cand, block_tables, bs)
+    with jax.named_scope(phases.STAGE2):
+        if use_kernels:
+            est = _rerank_paged_kernel_batched(pool, cand_phys, cand, qt,
+                                               enc_end, cfg)
+        else:
+            est = rerank_paged(pool.meta_codes, pool.meta_w, cand_phys, cand,
+                               qt, enc_end, cfg)
+        top_est, top_pos = jax.lax.top_k(est, top_k)
+        top_idx = jnp.take_along_axis(cand, top_pos, axis=-1)
+        safe_blk, off, phys_rows = _block_relative(top_idx, block_tables, bs)
     return PagedRetrievalResult(
         indices=top_idx, block_ids=safe_blk, offsets=off,
         phys_rows=phys_rows, scores=top_est,
@@ -518,9 +529,10 @@ def retrieve_paged_fused_sharded(pool, block_tables: jax.Array,
                                cfg, num_candidates, top_k,
                                bucket_select=bucket_select,
                                use_kernels=use_kernels)
-    return PagedRetrievalResult(*[
-        jax.lax.all_gather(leaf, axis_name, axis=1, tiled=True)
-        for leaf in res])
+    with jax.named_scope(phases.STAGE2):
+        return PagedRetrievalResult(*[
+            jax.lax.all_gather(leaf, axis_name, axis=1, tiled=True)
+            for leaf in res])
 
 
 def tiered_winner_rows(phys_rows: jax.Array, dev_map: jax.Array,

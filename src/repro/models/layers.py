@@ -24,6 +24,7 @@ import numpy as np
 from repro.core import attention as A
 from repro.core import cache as C
 from repro.core import encode as E
+from repro.core import phases
 from repro.core import retrieval as R
 from repro.core.config import ParisKVConfig
 
@@ -209,21 +210,28 @@ def _decode_qkv(p: dict, x_t: jax.Array, spec: AttnSpec, pos: jax.Array):
     per-row position ``pos`` ((b,) int32; scalar broadcasts)."""
     b, _ = x_t.shape
     H, G, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
-    q = x_t @ p["wq"]
-    k = x_t @ p["wk"]
-    v = x_t @ p["wv"]
-    if spec.qkv_bias:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = q.reshape(b, 1, H, hd)
-    k = k.reshape(b, 1, G, hd)
-    v = v.reshape(b, 1, G, hd)
-    if spec.qk_norm:
-        q = rms_norm(q, p["q_norm"], plus_one=True)
-        k = rms_norm(k, p["k_norm"], plus_one=True)
-    pos_arr = jnp.broadcast_to(jnp.asarray(pos), (b,))[:, None]
-    q = rope(q, pos_arr, spec.rope_theta)
-    k = rope(k, pos_arr, spec.rope_theta)
-    return q[:, 0], k[:, 0], v[:, 0]
+    with jax.named_scope(phases.DENSE):
+        q = x_t @ p["wq"]
+        k = x_t @ p["wk"]
+        v = x_t @ p["wv"]
+        if spec.qkv_bias:
+            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+        q = q.reshape(b, 1, H, hd)
+        k = k.reshape(b, 1, G, hd)
+        v = v.reshape(b, 1, G, hd)
+        if spec.qk_norm:
+            q = rms_norm(q, p["q_norm"], plus_one=True)
+            k = rms_norm(k, p["k_norm"], plus_one=True)
+        pos_arr = jnp.broadcast_to(jnp.asarray(pos), (b,))[:, None]
+        q = rope(q, pos_arr, spec.rope_theta)
+        k = rope(k, pos_arr, spec.rope_theta)
+        return q[:, 0], k[:, 0], v[:, 0]
+
+
+def _decode_out(p: dict, out: jax.Array, dtype) -> jax.Array:
+    """Output projection of one decoded token's attention heads."""
+    with jax.named_scope(phases.DENSE):
+        return out.reshape(out.shape[0], -1).astype(dtype) @ p["wo"]
 
 
 def attn_fill_chunk(p: dict, x: jax.Array, spec: AttnSpec, q_pos: jax.Array,
@@ -324,23 +332,25 @@ def distributed_retrieve_fetch(q_grp: jax.Array, layer_cache: C.LayerKVCache,
                                  (q.shape[0], q.shape[1], 1, n_loc))
         res = R.retrieve(meta, qt, valid, pcfg, C_loc, k_top,
                          hist_sample=pcfg.hist_sample)
-        glob_idx = res.indices + base
-        all_scores = jnp.moveaxis(
-            jax.lax.all_gather(res.scores, seq_tuple), 0, -2).reshape(
-                res.scores.shape[:-1] + (n_shards * k_top,))
-        all_idx = jnp.moveaxis(
-            jax.lax.all_gather(glob_idx, seq_tuple), 0, -2).reshape(
-                glob_idx.shape[:-1] + (n_shards * k_top,))
-        _, ppos = jax.lax.top_k(all_scores, k_top)
-        final_idx = jnp.take_along_axis(all_idx, ppos, -1)
+        with jax.named_scope(phases.STAGE2):
+            glob_idx = res.indices + base
+            all_scores = jnp.moveaxis(
+                jax.lax.all_gather(res.scores, seq_tuple), 0, -2).reshape(
+                    res.scores.shape[:-1] + (n_shards * k_top,))
+            all_idx = jnp.moveaxis(
+                jax.lax.all_gather(glob_idx, seq_tuple), 0, -2).reshape(
+                    glob_idx.shape[:-1] + (n_shards * k_top,))
+            _, ppos = jax.lax.top_k(all_scores, k_top)
+            final_idx = jnp.take_along_axis(all_idx, ppos, -1)
 
-        loc_idx = final_idx - base
-        mine = (loc_idx >= 0) & (loc_idx < n_loc)
-        safe = jnp.clip(loc_idx, 0, n_loc - 1)
-        k_rows = gather_kv_heads(k_cache, safe) * mine[..., None]
-        v_rows = gather_kv_heads(v_cache, safe) * mine[..., None]
-        k_sel = jax.lax.psum(k_rows.astype(jnp.float32), seq_tuple)
-        v_sel = jax.lax.psum(v_rows.astype(jnp.float32), seq_tuple)
+        with jax.named_scope(phases.ATTEND):
+            loc_idx = final_idx - base
+            mine = (loc_idx >= 0) & (loc_idx < n_loc)
+            safe = jnp.clip(loc_idx, 0, n_loc - 1)
+            k_rows = gather_kv_heads(k_cache, safe) * mine[..., None]
+            v_rows = gather_kv_heads(v_cache, safe) * mine[..., None]
+            k_sel = jax.lax.psum(k_rows.astype(jnp.float32), seq_tuple)
+            v_sel = jax.lax.psum(v_rows.astype(jnp.float32), seq_tuple)
         return final_idx, k_sel, v_sel
 
     P = jax.sharding.PartitionSpec
@@ -388,24 +398,25 @@ def attn_decode_pariskv_paged_fused(p: dict, x_t: jax.Array,
     H, G, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
     pos = jnp.broadcast_to(jnp.asarray(regions.pos, jnp.int32), (b,)) + 1
     q, k_t, v_t = _decode_qkv(p, x_t, spec, pos)
-    pool = C.paged_decode_append(pool, block_tables, k_t, v_t, pos)
+    with jax.named_scope(phases.ATTEND):
+        pool = C.paged_decode_append(pool, block_tables, k_t, v_t, pos)
 
     q_grp = q.reshape(b, G, H // G, hd)
     qt = E.encode_query(q_grp, pcfg, signs)
     enc_b = jnp.broadcast_to(jnp.asarray(regions.enc_end, jnp.int32), (b,))
     res = R.retrieve_paged_fused(pool, block_tables, qt, hist, enc_b, pcfg,
                                  num_candidates, pcfg.top_k)
-    k_ret = C.gather_heads_physical(pool.k, res.phys_rows)
-    v_ret = C.gather_heads_physical(pool.v, res.phys_rows)
-
-    W = C.window_size(pcfg)
-    ws = jnp.maximum(pos + 1 - W, 0)
-    out = A.sparse_decode_attention_paged(
-        q, pool.k, pool.v, block_tables, res.indices, ws, pos,
-        regions.enc_end, sink_size=pcfg.sink_size, window_size=W,
-        sm_scale=spec.scale(), softcap=spec.softcap,
-        k_ret=k_ret, v_ret=v_ret)
-    return out.reshape(b, -1).astype(x_t.dtype) @ p["wo"], pool
+    with jax.named_scope(phases.ATTEND):
+        k_ret = C.gather_heads_physical(pool.k, res.phys_rows)
+        v_ret = C.gather_heads_physical(pool.v, res.phys_rows)
+        W = C.window_size(pcfg)
+        ws = jnp.maximum(pos + 1 - W, 0)
+        out = A.sparse_decode_attention_paged(
+            q, pool.k, pool.v, block_tables, res.indices, ws, pos,
+            regions.enc_end, sink_size=pcfg.sink_size, window_size=W,
+            sm_scale=spec.scale(), softcap=spec.softcap,
+            k_ret=k_ret, v_ret=v_ret)
+    return _decode_out(p, out, x_t.dtype), pool
 
 
 def attn_decode_pariskv_paged(p: dict, x_t: jax.Array,
@@ -430,33 +441,35 @@ def attn_decode_pariskv_paged(p: dict, x_t: jax.Array,
     H, G, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
     pos = jnp.broadcast_to(jnp.asarray(regions.pos, jnp.int32), (b,)) + 1
     q, k_t, v_t = _decode_qkv(p, x_t, spec, pos)
-    pool = C.paged_decode_append(pool, block_tables, k_t, v_t, pos)
+    with jax.named_scope(phases.ATTEND):
+        pool = C.paged_decode_append(pool, block_tables, k_t, v_t, pos)
 
     bs = C.paged_block_size(pool)
     n_log = block_tables.shape[1] * bs
     q_grp = q.reshape(b, G, H // G, hd)
-    ids, codes, w = C.paged_meta_view(pool, block_tables)  # (b, G, n_log, B)
-    meta = E.KeyMetadata(ids, codes, w)
-    valid = C.retrieval_valid_mask(n_log, regions, pcfg)
-    if valid.ndim == 1:
-        valid = valid[None]
-    valid = jnp.broadcast_to(valid[:, None, None, :], (b, G, 1, n_log))
+    with jax.named_scope(phases.STAGE1):
+        ids, codes, w = C.paged_meta_view(pool, block_tables)  # (b,G,n_log,B)
+        meta = E.KeyMetadata(ids, codes, w)
+        valid = C.retrieval_valid_mask(n_log, regions, pcfg)
+        if valid.ndim == 1:
+            valid = valid[None]
+        valid = jnp.broadcast_to(valid[:, None, None, :], (b, G, 1, n_log))
     qt = E.encode_query(q_grp, pcfg, signs)
     meta_b = jax.tree.map(lambda a: a[:, :, None], meta)   # (b, G, 1, n, B)
     res = R.retrieve_paged(meta_b, qt, valid, pcfg, num_candidates,
                            pcfg.top_k, block_tables, bs,
                            hist_sample=pcfg.hist_sample)
-    k_ret = C.gather_heads_physical(pool.k, res.phys_rows)
-    v_ret = C.gather_heads_physical(pool.v, res.phys_rows)
-
-    W = C.window_size(pcfg)
-    ws = jnp.maximum(pos + 1 - W, 0)
-    out = A.sparse_decode_attention_paged(
-        q, pool.k, pool.v, block_tables, res.indices, ws, pos,
-        regions.enc_end, sink_size=pcfg.sink_size, window_size=W,
-        sm_scale=spec.scale(), softcap=spec.softcap,
-        k_ret=k_ret, v_ret=v_ret)
-    return out.reshape(b, -1).astype(x_t.dtype) @ p["wo"], pool
+    with jax.named_scope(phases.ATTEND):
+        k_ret = C.gather_heads_physical(pool.k, res.phys_rows)
+        v_ret = C.gather_heads_physical(pool.v, res.phys_rows)
+        W = C.window_size(pcfg)
+        ws = jnp.maximum(pos + 1 - W, 0)
+        out = A.sparse_decode_attention_paged(
+            q, pool.k, pool.v, block_tables, res.indices, ws, pos,
+            regions.enc_end, sink_size=pcfg.sink_size, window_size=W,
+            sm_scale=spec.scale(), softcap=spec.softcap,
+            k_ret=k_ret, v_ret=v_ret)
+    return _decode_out(p, out, x_t.dtype), pool
 
 
 def attn_decode_pariskv_paged_sharded(p: dict, x_t: jax.Array,
@@ -489,9 +502,10 @@ def attn_decode_pariskv_paged_sharded(p: dict, x_t: jax.Array,
     g0 = jax.lax.axis_index(axis_name) * G_loc
     pos = jnp.broadcast_to(jnp.asarray(regions.pos, jnp.int32), (b,)) + 1
     q, k_t, v_t = _decode_qkv(p, x_t, spec, pos)
-    k_loc = jax.lax.dynamic_slice_in_dim(k_t, g0, G_loc, axis=1)
-    v_loc = jax.lax.dynamic_slice_in_dim(v_t, g0, G_loc, axis=1)
-    pool = C.paged_decode_append(pool, block_tables, k_loc, v_loc, pos)
+    with jax.named_scope(phases.ATTEND):
+        k_loc = jax.lax.dynamic_slice_in_dim(k_t, g0, G_loc, axis=1)
+        v_loc = jax.lax.dynamic_slice_in_dim(v_t, g0, G_loc, axis=1)
+        pool = C.paged_decode_append(pool, block_tables, k_loc, v_loc, pos)
 
     q_grp = q.reshape(b, G, Hg, hd)
     q_loc = jax.lax.dynamic_slice_in_dim(q_grp, g0, G_loc, axis=1)
@@ -503,29 +517,31 @@ def attn_decode_pariskv_paged_sharded(p: dict, x_t: jax.Array,
     else:
         bs = C.paged_block_size(pool)
         n_log = block_tables.shape[1] * bs
-        ids, codes, w = C.paged_meta_view(pool, block_tables)
-        meta = E.KeyMetadata(ids, codes, w)
-        valid = C.retrieval_valid_mask(n_log, regions, pcfg)
-        if valid.ndim == 1:
-            valid = valid[None]
-        valid = jnp.broadcast_to(valid[:, None, None, :],
-                                 (b, G_loc, 1, n_log))
+        with jax.named_scope(phases.STAGE1):
+            ids, codes, w = C.paged_meta_view(pool, block_tables)
+            meta = E.KeyMetadata(ids, codes, w)
+            valid = C.retrieval_valid_mask(n_log, regions, pcfg)
+            if valid.ndim == 1:
+                valid = valid[None]
+            valid = jnp.broadcast_to(valid[:, None, None, :],
+                                     (b, G_loc, 1, n_log))
         meta_b = jax.tree.map(lambda a: a[:, :, None], meta)
         res = R.retrieve_paged(meta_b, qt, valid, pcfg, num_candidates,
                                pcfg.top_k, block_tables, bs,
                                hist_sample=pcfg.hist_sample)
-    k_ret = C.gather_heads_physical(pool.k, res.phys_rows)
-    v_ret = C.gather_heads_physical(pool.v, res.phys_rows)
-
-    W = C.window_size(pcfg)
-    ws = jnp.maximum(pos + 1 - W, 0)
-    out = A.sparse_decode_attention_paged(
-        q_loc.reshape(b, G_loc * Hg, hd), pool.k, pool.v, block_tables,
-        res.indices, ws, pos, regions.enc_end, sink_size=pcfg.sink_size,
-        window_size=W, sm_scale=spec.scale(), softcap=spec.softcap,
-        k_ret=k_ret, v_ret=v_ret)
-    out = jax.lax.all_gather(out, axis_name, axis=1, tiled=True)  # (b,H,hd)
-    return out.reshape(b, -1).astype(x_t.dtype) @ p["wo"], pool
+    with jax.named_scope(phases.ATTEND):
+        k_ret = C.gather_heads_physical(pool.k, res.phys_rows)
+        v_ret = C.gather_heads_physical(pool.v, res.phys_rows)
+        W = C.window_size(pcfg)
+        ws = jnp.maximum(pos + 1 - W, 0)
+        out = A.sparse_decode_attention_paged(
+            q_loc.reshape(b, G_loc * Hg, hd), pool.k, pool.v, block_tables,
+            res.indices, ws, pos, regions.enc_end, sink_size=pcfg.sink_size,
+            window_size=W, sm_scale=spec.scale(), softcap=spec.softcap,
+            k_ret=k_ret, v_ret=v_ret)
+        out = jax.lax.all_gather(out, axis_name, axis=1,
+                                 tiled=True)                # (b, H, hd)
+    return _decode_out(p, out, x_t.dtype), pool
 
 
 def attn_fill_chunk_sharded(p: dict, x: jax.Array, spec: AttnSpec,
@@ -611,8 +627,9 @@ def attn_decode_pariskv_tiered(p: dict, x_t: jax.Array,
     H, G, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
     pos = jnp.broadcast_to(jnp.asarray(regions.pos, jnp.int32), (b,)) + 1
     q, k_t, v_t = _decode_qkv(p, x_t, spec, pos)
-    bt_dev = C.tiered_kv_tables(block_tables, dev_map)
-    pool = C.paged_decode_append(pool, bt_dev, k_t, v_t, pos)
+    with jax.named_scope(phases.ATTEND):
+        bt_dev = C.tiered_kv_tables(block_tables, dev_map)
+        pool = C.paged_decode_append(pool, bt_dev, k_t, v_t, pos)
 
     bs = C.paged_block_size(pool)
     q_grp = q.reshape(b, G, H // G, hd)
@@ -623,86 +640,89 @@ def attn_decode_pariskv_tiered(p: dict, x_t: jax.Array,
                                      pcfg, num_candidates, pcfg.top_k)
     else:
         n_log = block_tables.shape[1] * bs
-        ids, codes, w = C.paged_meta_view(pool, block_tables)
-        meta = E.KeyMetadata(ids, codes, w)
-        valid = C.retrieval_valid_mask(n_log, regions, pcfg)
-        if valid.ndim == 1:
-            valid = valid[None]
-        valid = jnp.broadcast_to(valid[:, None, None, :], (b, G, 1, n_log))
+        with jax.named_scope(phases.STAGE1):
+            ids, codes, w = C.paged_meta_view(pool, block_tables)
+            meta = E.KeyMetadata(ids, codes, w)
+            valid = C.retrieval_valid_mask(n_log, regions, pcfg)
+            if valid.ndim == 1:
+                valid = valid[None]
+            valid = jnp.broadcast_to(valid[:, None, None, :],
+                                     (b, G, 1, n_log))
         meta_b = jax.tree.map(lambda a: a[:, :, None], meta)
         res = R.retrieve_paged(meta_b, qt, valid, pcfg, num_candidates,
                                pcfg.top_k, block_tables, bs,
                                hist_sample=pcfg.hist_sample)
 
-    resident, stag_rows = R.tiered_winner_rows(res.phys_rows, dev_map, bs)
-    ret_valid = ((res.indices >= pcfg.sink_size)
-                 & (res.indices < enc_b[:, None, None, None]))
-    hit = ret_valid & resident
-    miss = ret_valid & ~resident
-    miss_rows = jnp.where(miss, res.phys_rows, -1).astype(jnp.int32)
-    W = C.window_size(pcfg)
-    ws = jnp.maximum(pos + 1 - W, 0)
+    with jax.named_scope(phases.ATTEND):
+        resident, stag_rows = R.tiered_winner_rows(res.phys_rows, dev_map, bs)
+        ret_valid = ((res.indices >= pcfg.sink_size)
+                     & (res.indices < enc_b[:, None, None, None]))
+        hit = ret_valid & resident
+        miss = ret_valid & ~resident
+        miss_rows = jnp.where(miss, res.phys_rows, -1).astype(jnp.int32)
+        W = C.window_size(pcfg)
+        ws = jnp.maximum(pos + 1 - W, 0)
 
-    if getattr(fetch, "pipelined", False):
-        # ---- overlapped path: begin → dense work → collect ------------
-        ticket = fetch.begin_heads(miss_rows, rep)
-        # fence: add the ticket-derived 0 to every dense gather's indices
-        # (bit-exact) so the gathers truly depend on the begin callback —
-        # optimization_barrier does NOT survive into the schedule …
-        z = fetch.fence(ticket)
-        sink_idx = jnp.broadcast_to(jnp.arange(pcfg.sink_size)[None],
-                                    (b, pcfg.sink_size)) + z
-        w_idx = ws[:, None] + jnp.arange(W)[None] + z
-        k_hit = C.gather_heads_physical(pool.k, stag_rows + z)
-        v_hit = C.gather_heads_physical(pool.v, stag_rows + z)
-        k_sink = C.paged_gather_rows(pool.k, bt_dev, sink_idx)
-        v_sink = C.paged_gather_rows(pool.v, bt_dev, sink_idx)
-        k_loc = C.paged_gather_rows(pool.k, bt_dev, w_idx)
-        v_loc = C.paged_gather_rows(pool.v, bt_dev, w_idx)
-        # the sink/window score einsums only need staging-resident keys,
-        # so they run in the overlap window too — same function the
-        # attention kernel would call, so the values are bit-identical
-        s_sink, s_loc = A.dense_segment_scores(
-            q_grp.astype(jnp.float32), k_sink, k_loc)
-        # … and the collect takes the dense outputs as extra callback
-        # operands, so it schedules after the work hiding the host copy
-        k_miss, v_miss, stall, retries, timeouts, f_ok = \
-            fetch.collect_heads(ticket, miss_rows.shape,
-                                k_hit, v_hit, v_sink, v_loc, s_sink, s_loc)
-        calls = jnp.int32(2)
-    else:
-        k_hit = C.gather_heads_physical(pool.k, stag_rows)
-        v_hit = C.gather_heads_physical(pool.v, stag_rows)
-        k_miss, v_miss, stall, retries, timeouts, f_ok = \
-            fetch.heads(miss_rows, rep)
-        k_sink = v_sink = k_loc = v_loc = s_sink = s_loc = None
-        calls = jnp.int32(1)
-    sel = resident[..., None]
-    k_ret = jnp.where(sel, k_hit, k_miss.astype(k_hit.dtype))
-    v_ret = jnp.where(sel, v_hit, v_miss.astype(v_hit.dtype))
-    # degraded-mode mask (ISSUE 10): ok=0 means the miss buffers are
-    # zeros — drop those winners from attention instead of mixing in
-    # garbage. All-resident steps are unaffected even when ok=0.
-    ret_keep = resident | (f_ok > 0)
-    degraded = ((miss.sum(axis=(1, 2, 3)) > 0)
-                & (f_ok == 0)).astype(jnp.int32)
+        if getattr(fetch, "pipelined", False):
+            # ---- overlapped path: begin → dense work → collect ------------
+            ticket = fetch.begin_heads(miss_rows, rep)
+            # fence: add the ticket-derived 0 to every dense gather's indices
+            # (bit-exact) so the gathers truly depend on the begin callback —
+            # optimization_barrier does NOT survive into the schedule …
+            z = fetch.fence(ticket)
+            sink_idx = jnp.broadcast_to(jnp.arange(pcfg.sink_size)[None],
+                                        (b, pcfg.sink_size)) + z
+            w_idx = ws[:, None] + jnp.arange(W)[None] + z
+            k_hit = C.gather_heads_physical(pool.k, stag_rows + z)
+            v_hit = C.gather_heads_physical(pool.v, stag_rows + z)
+            k_sink = C.paged_gather_rows(pool.k, bt_dev, sink_idx)
+            v_sink = C.paged_gather_rows(pool.v, bt_dev, sink_idx)
+            k_loc = C.paged_gather_rows(pool.k, bt_dev, w_idx)
+            v_loc = C.paged_gather_rows(pool.v, bt_dev, w_idx)
+            # the sink/window score einsums only need staging-resident keys,
+            # so they run in the overlap window too — same function the
+            # attention kernel would call, so the values are bit-identical
+            s_sink, s_loc = A.dense_segment_scores(
+                q_grp.astype(jnp.float32), k_sink, k_loc)
+            # … and the collect takes the dense outputs as extra callback
+            # operands, so it schedules after the work hiding the host copy
+            k_miss, v_miss, stall, retries, timeouts, f_ok = \
+                fetch.collect_heads(ticket, miss_rows.shape,
+                                    k_hit, v_hit, v_sink, v_loc, s_sink, s_loc)
+            calls = jnp.int32(2)
+        else:
+            k_hit = C.gather_heads_physical(pool.k, stag_rows)
+            v_hit = C.gather_heads_physical(pool.v, stag_rows)
+            k_miss, v_miss, stall, retries, timeouts, f_ok = \
+                fetch.heads(miss_rows, rep)
+            k_sink = v_sink = k_loc = v_loc = s_sink = s_loc = None
+            calls = jnp.int32(1)
+        sel = resident[..., None]
+        k_ret = jnp.where(sel, k_hit, k_miss.astype(k_hit.dtype))
+        v_ret = jnp.where(sel, v_hit, v_miss.astype(v_hit.dtype))
+        # degraded-mode mask: ok=0 means the miss buffers are
+        # zeros — drop those winners from attention instead of mixing in
+        # garbage. All-resident steps are unaffected even when ok=0.
+        ret_keep = resident | (f_ok > 0)
+        degraded = ((miss.sum(axis=(1, 2, 3)) > 0)
+                    & (f_ok == 0)).astype(jnp.int32)
 
-    nb = dev_map.shape[0]
-    host_blk = res.phys_rows // bs
-    touched = jnp.zeros((nb,), jnp.int32).at[
-        jnp.where(ret_valid, host_blk, nb)].add(1, mode="drop")
-    rows = jnp.stack([ret_valid.sum(axis=(1, 2, 3)).astype(jnp.int32),
-                      hit.sum(axis=(1, 2, 3)).astype(jnp.int32),
-                      miss.sum(axis=(1, 2, 3)).astype(jnp.int32)], axis=-1)
+        nb = dev_map.shape[0]
+        host_blk = res.phys_rows // bs
+        touched = jnp.zeros((nb,), jnp.int32).at[
+            jnp.where(ret_valid, host_blk, nb)].add(1, mode="drop")
+        rows = jnp.stack([ret_valid.sum(axis=(1, 2, 3)).astype(jnp.int32),
+                          hit.sum(axis=(1, 2, 3)).astype(jnp.int32),
+                          miss.sum(axis=(1, 2, 3)).astype(jnp.int32)], axis=-1)
 
-    out = A.sparse_decode_attention_tiered(
-        q, pool.k, pool.v, block_tables, dev_map, res.indices, ws, pos,
-        regions.enc_end, sink_size=pcfg.sink_size, window_size=W,
-        sm_scale=spec.scale(), softcap=spec.softcap,
-        k_ret=k_ret, v_ret=v_ret, k_sink=k_sink, v_sink=v_sink,
-        k_loc=k_loc, v_loc=v_loc, s_sink=s_sink, s_loc=s_loc,
-        ret_keep=ret_keep)
-    y = out.reshape(b, -1).astype(x_t.dtype) @ p["wo"]
+        out = A.sparse_decode_attention_tiered(
+            q, pool.k, pool.v, block_tables, dev_map, res.indices, ws, pos,
+            regions.enc_end, sink_size=pcfg.sink_size, window_size=W,
+            sm_scale=spec.scale(), softcap=spec.softcap,
+            k_ret=k_ret, v_ret=v_ret, k_sink=k_sink, v_sink=v_sink,
+            k_loc=k_loc, v_loc=v_loc, s_sink=s_sink, s_loc=s_loc,
+            ret_keep=ret_keep)
+    y = _decode_out(p, out, x_t.dtype)
     return y, pool, {"touched": touched, "rows": rows,
                      "stall": stall.astype(jnp.float32), "calls": calls,
                      "retries": retries, "timeouts": timeouts,
@@ -724,7 +744,8 @@ def attn_decode_pariskv(p: dict, x_t: jax.Array, layer_cache: C.LayerKVCache,
     H, G, hd = spec.num_heads, spec.num_kv_heads, spec.head_dim
     pos = jnp.broadcast_to(jnp.asarray(regions.pos, jnp.int32), (b,)) + 1
     q, k_t, v_t = _decode_qkv(p, x_t, spec, pos)
-    layer_cache = C.decode_append(layer_cache, k_t, v_t, pos)
+    with jax.named_scope(phases.ATTEND):
+        layer_cache = C.decode_append(layer_cache, k_t, v_t, pos)
 
     n_max = layer_cache.k.shape[1]
     q_grp = q.reshape(b, G, H // G, hd)
@@ -738,21 +759,24 @@ def attn_decode_pariskv(p: dict, x_t: jax.Array, layer_cache: C.LayerKVCache,
     else:
         meta = E.KeyMetadata(layer_cache.meta_ids, layer_cache.meta_codes,
                              layer_cache.meta_w)
-        valid = C.retrieval_valid_mask(n_max, regions, pcfg)
-        if valid.ndim == 1:                       # scalar-region call site
-            valid = valid[None]
-        valid = jnp.broadcast_to(valid[:, None, None, :], (b, G, 1, n_max))
+        with jax.named_scope(phases.STAGE1):
+            valid = C.retrieval_valid_mask(n_max, regions, pcfg)
+            if valid.ndim == 1:                   # scalar-region call site
+                valid = valid[None]
+            valid = jnp.broadcast_to(valid[:, None, None, :],
+                                     (b, G, 1, n_max))
         qt = E.encode_query(q_grp, pcfg, signs)
         meta_b = jax.tree.map(lambda a: a[:, :, None], meta)  # (b,G,1,n,B)
         res = R.retrieve(meta_b, qt, valid, pcfg, num_candidates, pcfg.top_k,
                          hist_sample=pcfg.hist_sample)
         top_idx = res.indices
 
-    W = C.window_size(pcfg)
-    ws = jnp.maximum(pos + 1 - W, 0)
-    out = A.sparse_decode_attention(
-        q, layer_cache.k, layer_cache.v, top_idx, ws, pos,
-        regions.enc_end, sink_size=pcfg.sink_size, window_size=W,
-        sm_scale=spec.scale(), softcap=spec.softcap,
-        k_ret=k_ret, v_ret=v_ret)
-    return out.reshape(b, -1).astype(x_t.dtype) @ p["wo"], layer_cache
+    with jax.named_scope(phases.ATTEND):
+        W = C.window_size(pcfg)
+        ws = jnp.maximum(pos + 1 - W, 0)
+        out = A.sparse_decode_attention(
+            q, layer_cache.k, layer_cache.v, top_idx, ws, pos,
+            regions.enc_end, sink_size=pcfg.sink_size, window_size=W,
+            sm_scale=spec.scale(), softcap=spec.softcap,
+            k_ret=k_ret, v_ret=v_ret)
+    return _decode_out(p, out, x_t.dtype), layer_cache

@@ -44,13 +44,13 @@ keep slot-local ring buffers a mapped prefix cannot populate.
 """
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import cache as CC
+from repro.core import phases
 from repro.core import srht
 from repro.core.config import ModelConfig
 from repro.models import layers as L
@@ -444,11 +444,8 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
     take the fused retrieval path (no per-step meta-view gather, Stage-I
     histogram from the ``hist`` cache entry) unless ``paged_fused`` is
     False. ``hist`` is maintained at promotion on *both* paged paths, so
-    the flag can flip between runs without invalidating state. (The
-    REPRO_NO_PROMOTE bisection knob skips that maintenance along with the
-    promotion itself — with it set, fused and meta-view scores diverge
-    once enc_end outruns the stale histogram, which is exactly the stale-
-    metadata regime the knob exists to measure.)
+    the flag can flip between runs without invalidating state; its cost
+    shows under the ``pariskv.promote`` scope (core.phases).
 
     ``dev_map`` switches paged ParisKV layers to the **tiered** pool
     (ISSUE 6): retrieval runs unchanged over the device-resident
@@ -458,10 +455,12 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
     per-step fetch stats land in the ``fetch`` cache leaves."""
     pcfg = cfg.pariskv
     b = x_t.shape[0]
-    h = L.rms_norm(x_t[:, None], p["norm_attn"], cfg.norm_eps)[:, 0]
+    with jax.named_scope(phases.DENSE):
+        h = L.rms_norm(x_t[:, None], p["norm_attn"], cfg.norm_eps)[:, 0]
     pos = regions.pos + 1
     promote_mask = jnp.broadcast_to(jnp.asarray(will_promote), (b,))
 
+    @jax.named_scope(phases.PROMOTE)
     def maybe_promote_rows(c):
         return jax.lax.cond(
             jnp.any(promote_mask),
@@ -469,6 +468,7 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
                                        pcfg, signs),
             lambda cc: cc, c)
 
+    @jax.named_scope(phases.PROMOTE)
     def maybe_promote_paged(c, hist):
         kvt = (None if dev_map is None
                else CC.tiered_kv_tables(block_tables, dev_map))
@@ -532,11 +532,7 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
     if ld.mixer == "attn":
         if ld.use_pariskv:
             y, kvc = pariskv_decode(cache["kv"])
-            if os.environ.get("REPRO_NO_PROMOTE") != "1":  # cost bisection
-                cache = {**cache, **promote_and_store(kvc)}
-            else:
-                cache = {**cache, "kv": kvc}
-            cache = merge_fetch_stats(cache)
+            cache = merge_fetch_stats({**cache, **promote_and_store(kvc)})
         elif isinstance(cache["kv"], CC.LayerKVCache):
             # baseline full-attention decode over the ParisKV store
             y, kv = L.attn_decode_dense(
@@ -549,11 +545,12 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
     elif ld.mixer == "mla":
         y, mc = MLA.mla_decode(p["attn"], h, cache["kv"], regions, cfg, signs,
                                num_candidates)
-        mc = jax.lax.cond(
-            jnp.any(promote_mask),
-            lambda c: MLA.mla_promote_rows(c, regions.enc_end, promote_mask,
-                                           pcfg, signs),
-            lambda c: c, mc)
+        with jax.named_scope(phases.PROMOTE):
+            mc = jax.lax.cond(
+                jnp.any(promote_mask),
+                lambda c: MLA.mla_promote_rows(c, regions.enc_end,
+                                               promote_mask, pcfg, signs),
+                lambda c: c, mc)
         cache = {**cache, "kv": mc}
     elif ld.mixer == "cross":
         km, vm = cache["media_kv"]
@@ -573,7 +570,8 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
         y = 0.5 * (ya + ys)
         cache = merge_fetch_stats({**cache, **promote_and_store(kvc),
                                    "ssm": sc})
-    x_t = x_t + y.astype(x_t.dtype)
+    with jax.named_scope(phases.DENSE):
+        x_t = x_t + y.astype(x_t.dtype)
     if ld.cross:
         h = L.rms_norm(x_t[:, None], p["norm_cross"], cfg.norm_eps)[:, 0]
         km, vm = cache["media_kv"]
@@ -584,12 +582,13 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
                              sm_scale=ld.attn.scale())[:, 0]
         x_t = x_t + (out.reshape(h.shape[0], -1) @ p["cross"]["wo"]).astype(x_t.dtype)
     if ld.ffn != "none":
-        h = L.rms_norm(x_t[:, None], p["norm_mlp"], cfg.norm_eps)[:, 0]
-        if ld.ffn == "moe":
-            y = MOE.moe_decode(p["moe"], h, cfg.experts_per_token)
-        else:
-            y = L.mlp_fwd(p["mlp"], h)
-        x_t = x_t + y.astype(x_t.dtype)
+        with jax.named_scope(phases.DENSE):
+            h = L.rms_norm(x_t[:, None], p["norm_mlp"], cfg.norm_eps)[:, 0]
+            if ld.ffn == "moe":
+                y = MOE.moe_decode(p["moe"], h, cfg.experts_per_token)
+            else:
+                y = L.mlp_fwd(p["mlp"], h)
+            x_t = x_t + y.astype(x_t.dtype)
     return x_t, cache
 
 
@@ -903,14 +902,15 @@ def _stage_pass(params, cfg: ModelConfig, x_t, caches, regions, signs,
                     block_tables=block_tables, paged_fused=paged_fused,
                     dev_map=dev_map, fetch=fe, rep=rep)
                 if x_f is not None:
-                    x_f, c = jax.lax.cond(
-                        any_fill,
-                        lambda op, p_l=p_slice[f"l{i}"], ld_l=ld_eff,
-                               fe_l=fe, rep_l=rep:
-                            _layer_fill(p_l, op[0], ld_l, cfg, op[1], fctx,
-                                        signs, fetch=fe_l, rep=rep_l,
-                                        dist=dist),
-                        lambda op: op, (x_f, c))
+                    with jax.named_scope(phases.FILL):
+                        x_f, c = jax.lax.cond(
+                            any_fill,
+                            lambda op, p_l=p_slice[f"l{i}"], ld_l=ld_eff,
+                                   fe_l=fe, rep_l=rep:
+                                _layer_fill(p_l, op[0], ld_l, cfg, op[1],
+                                            fctx, signs, fetch=fe_l,
+                                            rep=rep_l, dist=dist),
+                            lambda op: op, (x_f, c))
                 new_c[f"l{i}"] = c
             return (x_t, x_f), new_c
 
@@ -945,7 +945,8 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, state: ServeState,
     pcfg = cfg.pariskv
     b = token.shape[0]
     signs = rotation_signs(cfg)
-    x_t = _embed(params, cfg, token[:, None])[:, 0]
+    with jax.named_scope(phases.DENSE):
+        x_t = _embed(params, cfg, token[:, None])[:, 0]
     pos_b = jnp.broadcast_to(jnp.asarray(state.regions.pos, jnp.int32), (b,))
     enc_b = jnp.broadcast_to(jnp.asarray(state.regions.enc_end, jnp.int32),
                              (b,))
@@ -969,8 +970,10 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, state: ServeState,
         will_promote, use_pariskv, dist, block_tables, paged_fused,
         dev_map=dev_map, fetch=fetch)
 
-    x_t = L.rms_norm(x_t[:, None], params["final_norm"], cfg.norm_eps)[:, 0]
-    logits = _unembed(params, cfg, x_t)
+    with jax.named_scope(phases.DENSE):
+        x_t = L.rms_norm(x_t[:, None], params["final_norm"],
+                         cfg.norm_eps)[:, 0]
+        logits = _unembed(params, cfg, x_t)
     new_regions = CC.CacheRegions(
         pos=jnp.where(act, pos_b + 1, pos_b),
         enc_end=jnp.where(will_promote, enc_b + pcfg.update_interval, enc_b))
@@ -996,14 +999,17 @@ def decode_fill_step(params, cfg: ModelConfig, token: jax.Array,
     pcfg = cfg.pariskv
     b = token.shape[0]
     signs = rotation_signs(cfg)
-    x_t = _embed(params, cfg, token[:, None])[:, 0]
+    with jax.named_scope(phases.DENSE):
+        x_t = _embed(params, cfg, token[:, None])[:, 0]
     # the fill-side embed only runs when something fills — steady-state
     # pure-decode steps (all fills complete) skip it along with the
     # per-layer fill branches and the fill-logits head below
-    emb_sds = jax.eval_shape(lambda t: _embed(params, cfg, t), fill_tokens)
-    x_f = jax.lax.cond(
-        any_fill, lambda t: _embed(params, cfg, t),
-        lambda t: jnp.zeros(emb_sds.shape, emb_sds.dtype), fill_tokens)
+    with jax.named_scope(phases.FILL):
+        emb_sds = jax.eval_shape(lambda t: _embed(params, cfg, t),
+                                 fill_tokens)
+        x_f = jax.lax.cond(
+            any_fill, lambda t: _embed(params, cfg, t),
+            lambda t: jnp.zeros(emb_sds.shape, emb_sds.dtype), fill_tokens)
     pos_b = jnp.broadcast_to(jnp.asarray(state.regions.pos, jnp.int32), (b,))
     enc_b = jnp.broadcast_to(jnp.asarray(state.regions.enc_end, jnp.int32),
                              (b,))
@@ -1027,8 +1033,10 @@ def decode_fill_step(params, cfg: ModelConfig, token: jax.Array,
         will_promote, use_pariskv, dist, block_tables, paged_fused,
         x_f=x_f, fctx=fctx, any_fill=any_fill, dev_map=dev_map, fetch=fetch)
 
-    x_t = L.rms_norm(x_t[:, None], params["final_norm"], cfg.norm_eps)[:, 0]
-    logits = _unembed(params, cfg, x_t)
+    with jax.named_scope(phases.DENSE):
+        x_t = L.rms_norm(x_t[:, None], params["final_norm"],
+                         cfg.norm_eps)[:, 0]
+        logits = _unembed(params, cfg, x_t)
 
     def fill_head(xf):
         x_fn = L.rms_norm(xf, params["final_norm"], cfg.norm_eps)
@@ -1036,10 +1044,11 @@ def decode_fill_step(params, cfg: ModelConfig, token: jax.Array,
             x_fn, jnp.maximum(fctx.valid_n - 1, 0), 1, axis=1)[:, 0]
         return _unembed(params, cfg, x_last)
 
-    fl_sds = jax.eval_shape(fill_head, x_f)
-    fill_logits = jax.lax.cond(
-        any_fill, fill_head,
-        lambda xf: jnp.zeros(fl_sds.shape, fl_sds.dtype), x_f)
+    with jax.named_scope(phases.FILL):
+        fl_sds = jax.eval_shape(fill_head, x_f)
+        fill_logits = jax.lax.cond(
+            any_fill, fill_head,
+            lambda xf: jnp.zeros(fl_sds.shape, fl_sds.dtype), x_f)
     new_regions = CC.CacheRegions(
         pos=jnp.where(act, pos_b + 1, pos_b),
         enc_end=jnp.where(will_promote, enc_b + pcfg.update_interval, enc_b))
@@ -1206,12 +1215,13 @@ def decode_chunk(params, cfg: ModelConfig, state: SlotState, num_steps: int,
                                       block_tables=block_tables,
                                       paged_fused=paged_fused,
                                       dev_map=dev_map, fetch=fetch)
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            emit = jnp.where(active, nxt, -1)
-            rem = st.remaining - active.astype(jnp.int32)
-            if eos_id is not None:
-                rem = jnp.where(active & (nxt == eos_id), 0, rem)
-            cur = jnp.where(active, nxt, st.cur_tok)
+            with jax.named_scope(phases.DENSE):
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                emit = jnp.where(active, nxt, -1)
+                rem = st.remaining - active.astype(jnp.int32)
+                if eos_id is not None:
+                    rem = jnp.where(active & (nxt == eos_id), 0, rem)
+                cur = jnp.where(active, nxt, st.cur_tok)
             return st._replace(caches=new.caches, regions=new.regions,
                                cur_tok=cur, remaining=rem), emit
 
@@ -1224,52 +1234,58 @@ def decode_chunk(params, cfg: ModelConfig, state: SlotState, num_steps: int,
     pcfg = cfg.pariskv
 
     def step(st, _):
-        filling = (st.fill_len > 0) & (st.fill_pos < st.fill_len)
-        any_fill = jnp.any(filling)
-        fslot = jnp.argmax(filling).astype(jnp.int32)
-        active = (st.remaining > 0) & ~filling
-        start = st.fill_pos[fslot]
-        flen = st.fill_len[fslot]
-        valid_n = jnp.clip(flen - start, 0, P)
-        q_pos = (start + jnp.arange(P))[None]
-        valid = (jnp.arange(P) < valid_n)[None]
-        fill_toks = jax.lax.dynamic_slice(st.prompt, (fslot, start), (1, P))
-        bt_row = None if block_tables is None else block_tables[fslot]
-        dev_row = (None if (block_tables is None or dev_map is None)
-                   else CC.tiered_kv_tables(bt_row[None], dev_map)[0])
-        fctx = FillCtx(slot=fslot, start=start, q_pos=q_pos, valid=valid,
-                       valid_n=valid_n, bt_row=bt_row, dev_row=dev_row)
+        with jax.named_scope(phases.FILL):
+            filling = (st.fill_len > 0) & (st.fill_pos < st.fill_len)
+            any_fill = jnp.any(filling)
+            fslot = jnp.argmax(filling).astype(jnp.int32)
+            active = (st.remaining > 0) & ~filling
+            start = st.fill_pos[fslot]
+            flen = st.fill_len[fslot]
+            valid_n = jnp.clip(flen - start, 0, P)
+            q_pos = (start + jnp.arange(P))[None]
+            valid = (jnp.arange(P) < valid_n)[None]
+            fill_toks = jax.lax.dynamic_slice(st.prompt, (fslot, start),
+                                              (1, P))
+            bt_row = None if block_tables is None else block_tables[fslot]
+            dev_row = (None if (block_tables is None or dev_map is None)
+                       else CC.tiered_kv_tables(bt_row[None], dev_map)[0])
+            fctx = FillCtx(slot=fslot, start=start, q_pos=q_pos,
+                           valid=valid, valid_n=valid_n, bt_row=bt_row,
+                           dev_row=dev_row)
         logits, fill_logits, new = decode_fill_step(
             params, cfg, st.cur_tok, ServeState(st.caches, st.regions),
             fill_toks, fctx, any_fill, use_pariskv=use_pariskv, dist=dist,
             active=active, block_tables=block_tables,
             paged_fused=paged_fused, dev_map=dev_map, fetch=fetch)
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        emit = jnp.where(active, nxt, -1)
-        rem = st.remaining - active.astype(jnp.int32)
-        if eos_id is not None:
-            rem = jnp.where(active & (nxt == eos_id), 0, rem)
-        cur = jnp.where(active, nxt, st.cur_tok)
+        with jax.named_scope(phases.DENSE):
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            emit = jnp.where(active, nxt, -1)
+            rem = st.remaining - active.astype(jnp.int32)
+            if eos_id is not None:
+                rem = jnp.where(active & (nxt == eos_id), 0, rem)
+            cur = jnp.where(active, nxt, st.cur_tok)
 
         # --- fill bookkeeping: advance the frontier; on the completing
         # step the last prompt token's logits emit the first new token
-        f1 = start + valid_n
-        completed = any_fill & (f1 >= flen)
-        ftok = jnp.argmax(fill_logits[0], -1).astype(jnp.int32)
-        fill_pos = jnp.where(any_fill, st.fill_pos.at[fslot].set(f1),
-                             st.fill_pos)
-        pos2 = jnp.where(any_fill, new.regions.pos.at[fslot].set(f1 - 1),
-                         new.regions.pos)
-        enc2 = jnp.where(
-            any_fill,
-            new.regions.enc_end.at[fslot].set(CC.fill_enc_end(f1, pcfg)),
-            new.regions.enc_end)
-        emit = jnp.where(completed, emit.at[fslot].set(ftok), emit)
-        cur = jnp.where(completed, cur.at[fslot].set(ftok), cur)
-        rem_f = rem[fslot] - 1
-        if eos_id is not None:
-            rem_f = jnp.where(ftok == eos_id, 0, rem_f)
-        rem = jnp.where(completed, rem.at[fslot].set(rem_f), rem)
+        with jax.named_scope(phases.FILL):
+            f1 = start + valid_n
+            completed = any_fill & (f1 >= flen)
+            ftok = jnp.argmax(fill_logits[0], -1).astype(jnp.int32)
+            fill_pos = jnp.where(any_fill, st.fill_pos.at[fslot].set(f1),
+                                 st.fill_pos)
+            pos2 = jnp.where(any_fill,
+                             new.regions.pos.at[fslot].set(f1 - 1),
+                             new.regions.pos)
+            enc2 = jnp.where(
+                any_fill,
+                new.regions.enc_end.at[fslot].set(CC.fill_enc_end(f1, pcfg)),
+                new.regions.enc_end)
+            emit = jnp.where(completed, emit.at[fslot].set(ftok), emit)
+            cur = jnp.where(completed, cur.at[fslot].set(ftok), cur)
+            rem_f = rem[fslot] - 1
+            if eos_id is not None:
+                rem_f = jnp.where(ftok == eos_id, 0, rem_f)
+            rem = jnp.where(completed, rem.at[fslot].set(rem_f), rem)
         return SlotState(new.caches,
                          CC.CacheRegions(pos=pos2, enc_end=enc2),
                          cur, rem, fill_pos, st.fill_len, st.prompt), emit

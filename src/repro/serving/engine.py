@@ -35,7 +35,14 @@ end of the chunk in which the request finished (chunk-boundary
 granularity, ± chunk_size·TPOT). ``token_times`` records when each output
 token became host-visible (chunk granularity) — the decode-stall metric
 (max inter-token gap) in ``benchmarks/bench_continuous_batching.py`` is
-computed from it.
+computed from it. ``t_submit`` / ``t_admit`` stamp when ``submit()`` took
+a request and when it left the queue.
+
+Each serving round runs under host spans (``SPAN_*``: admit, pre-chunk,
+dispatch, wait, collect; ``jax.profiler.TraceAnnotation``), so a device
+trace taken around ``step_serve()`` says what the host was doing in each
+of the device's idle gaps; the decode chunk's own ops carry the phase
+scopes of ``core.phases``.
 
 ``PagedServingEngine`` replaces the per-slot contiguous ``n_max`` regions
 with a **paged KV cache**: one global pool of fixed-size token blocks
@@ -164,10 +171,22 @@ class Request:
     #                              window + resident-staged blocks only
     failed: bool = False         # quarantined by an engine fault
     error: Optional[str] = None  # the quarantining exception, rendered
+    # host-clock stamps (time.perf_counter): submit() took the request,
+    # and the engine popped it off the queue (None until then)
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
     # engine-internal:
     _tokens: Optional[list] = None
-    _t_admit: float = 0.0
     _t_first: float = 0.0
+
+
+# host spans of one serving round, on the profiler's clock: a device trace
+# taken around step_serve() shows which of them the host was in
+SPAN_ADMIT = "engine.admit"          # one request's admission (arg: uid)
+SPAN_PRE_CHUNK = "engine.pre_chunk"  # block allocation, staging update
+SPAN_DISPATCH = "engine.dispatch"    # launching the jitted decode chunk
+SPAN_WAIT = "engine.wait"            # blocking on the chunk's tokens
+SPAN_COLLECT = "engine.collect"      # fetch stats, tokens, finishes
 
 
 def _bucket(n: int, floor: int = 8, cap: Optional[int] = None) -> int:
@@ -293,6 +312,7 @@ class ServingEngine:
             raise ValueError(
                 f"request {req.uid}: prompt {len(req.prompt)} + "
                 f"{req.max_new_tokens} new tokens exceeds n_max={self.n_max}")
+        req.t_submit = time.perf_counter()
         self.queue.append(req)
 
     def cancel(self, uid: int) -> None:
@@ -362,7 +382,7 @@ class ServingEngine:
             self.queue.remove(req)
             req.cancelled = True
             req._tokens, req.token_times = [], []
-            req._t_first = req._t_admit = t_now
+            req._t_first = req.t_admit = t_now
             self._finish_request(req, t_now)
             self._cancelled.discard(req.uid)
         for slot, req in enumerate(self._slots):
@@ -442,43 +462,46 @@ class ServingEngine:
                 continue
             if not self._can_admit():
                 break                        # backpressure: head waits
-            if self.prefill_budget:
-                if self._filling is not None:
-                    break                    # at most one filling slot
-                req = self.queue.pop(0)
-                self._pre_admit(slot, req)
-                self._admit_chunked(slot, req)
-                continue
+            if self.prefill_budget and self._filling is not None:
+                break                        # at most one filling slot
             req = self.queue.pop(0)
-            t_admit = time.perf_counter()
-            try:
-                self._pre_admit(slot, req)
-                state1, tok0 = self._prefill_request(req)
-            except Exception as exc:  # noqa: BLE001 — quarantine boundary
-                self._quarantine_admission(slot, req, exc)
-                continue
-            t_first = time.perf_counter()
-            req.ttft_s = t_first - t_admit
-            req._t_first = t_first
-            req._tokens = [tok0]
-            req.token_times = [t_first]
-            if req.max_new_tokens <= 1 or tok0 == self.eos_id:
-                req.output = np.asarray(req._tokens, np.int32)
-                req.decode_s = 0.0
-                self._done.append(req)
-                self._abort_admit(slot)
-                continue
-            try:
-                self._install_solo(slot, req, state1, tok0)
-            except Exception as exc:  # noqa: BLE001 — quarantine boundary
-                self._quarantine_admission(slot, req, exc)
-                continue
-            self._slots[slot] = req
+            req.t_admit = time.perf_counter()
+            with jax.profiler.TraceAnnotation(SPAN_ADMIT, uid=req.uid):
+                self._admit_one(slot, req)
+
+    def _admit_one(self, slot: int, req: Request) -> None:
+        """Admit ``req`` (just popped off the queue) into ``slot``."""
+        if self.prefill_budget:
+            self._pre_admit(slot, req)
+            self._admit_chunked(slot, req)
+            return
+        try:
+            self._pre_admit(slot, req)
+            state1, tok0 = self._prefill_request(req)
+        except Exception as exc:  # noqa: BLE001 — quarantine boundary
+            self._quarantine_admission(slot, req, exc)
+            return
+        t_first = time.perf_counter()
+        req.ttft_s = t_first - req.t_admit
+        req._t_first = t_first
+        req._tokens = [tok0]
+        req.token_times = [t_first]
+        if req.max_new_tokens <= 1 or tok0 == self.eos_id:
+            req.output = np.asarray(req._tokens, np.int32)
+            req.decode_s = 0.0
+            self._done.append(req)
+            self._abort_admit(slot)
+            return
+        try:
+            self._install_solo(slot, req, state1, tok0)
+        except Exception as exc:  # noqa: BLE001 — quarantine boundary
+            self._quarantine_admission(slot, req, exc)
+            return
+        self._slots[slot] = req
 
     def _admit_chunked(self, slot: int, req: Request) -> None:
         """Chunked-prefill admission: copy the prompt to the device buffer
         and arm the slot's fill state — the decode chunk does the work."""
-        req._t_admit = time.perf_counter()
         req._tokens, req.token_times = [], []
         prow = np.zeros((self.n_max + self.prefill_budget,), np.int32)
         prow[:len(req.prompt)] = req.prompt
@@ -519,8 +542,10 @@ class ServingEngine:
         return self._chunk.lower(*self.chunk_args())
 
     def _run_chunk(self):
-        tokens, self._state = self._chunk(*self.chunk_args())
-        return np.asarray(tokens), np.asarray(self._state.remaining)
+        with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+            tokens, self._state = self._chunk(*self.chunk_args())
+        with jax.profiler.TraceAnnotation(SPAN_WAIT):
+            return np.asarray(tokens), np.asarray(self._state.remaining)
 
     def _release_slot(self, slot: int) -> None:
         """Hook: reclaim a finished slot's resources (paged: blocks)."""
@@ -530,7 +555,7 @@ class ServingEngine:
         had = len(req._tokens)
         n_emit = _collect_chunk_row(req, tokens[slot], t_now)
         if had == 0 and n_emit > 0:          # chunked fill completed
-            req.ttft_s = t_now - req._t_admit
+            req.ttft_s = t_now - req.t_admit
             req._t_first = t_now
             if self._filling == slot:
                 self._filling = None
@@ -574,9 +599,11 @@ class ServingEngine:
             sum(r is not None for r in self._slots))
         if all(r is None for r in self._slots):
             return      # everything finished at prefill; maybe more queued
-        self._pre_chunk()
+        with jax.profiler.TraceAnnotation(SPAN_PRE_CHUNK):
+            self._pre_chunk()
         tokens, rem_after = self._run_chunk()
-        self._collect(tokens, rem_after)
+        with jax.profiler.TraceAnnotation(SPAN_COLLECT):
+            self._collect(tokens, rem_after)
 
     def run(self) -> List[Request]:
         """Serve everything in the queue; returns completed requests."""
@@ -857,6 +884,7 @@ class PagedServingEngine(ServingEngine):
             raise ValueError(
                 f"request {req.uid}: needs {self.blocks_needed(req)} blocks, "
                 f"pool holds {self.num_blocks} — request can never run")
+        req.t_submit = time.perf_counter()
         self.queue.append(req)
 
     def _take_block(self, slot: int) -> None:
@@ -1057,7 +1085,6 @@ class PagedServingEngine(ServingEngine):
         slot's histogram can rebuild from the shared blocks' metadata."""
         if not self.share_prefixes:
             return super()._admit_chunked(slot, req)
-        req._t_admit = time.perf_counter()
         req._tokens, req.token_times = [], []
         prow = np.zeros((self.n_max + self.prefill_budget,), np.int32)
         prow[:len(req.prompt)] = req.prompt
@@ -1671,10 +1698,9 @@ class OffloadedPagedServingEngine(PagedServingEngine):
     def chunk_args(self) -> tuple:
         return super().chunk_args() + (jnp.asarray(self.staging.dev_map),)
 
-    def _run_chunk(self):
-        toks, rem = super()._run_chunk()
+    def _collect(self, tokens: np.ndarray, rem_after: np.ndarray) -> None:
         self._harvest_fetch_stats()
-        return toks, rem
+        super()._collect(tokens, rem_after)
 
     def _reclaim_slot(self, slot: int) -> None:
         """Reclaim both tiers — refcount-aware across them (ISSUE 7):

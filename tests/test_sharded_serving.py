@@ -249,3 +249,16 @@ def test_missing_devices_hint():
     with pytest.raises(ValueError, match="XLA_FLAGS"):
         PagedServingEngine(cfg, None, n_max=128, max_batch=1,
                            block_size=64, mesh_shards=too_many)
+
+
+@needs_mesh
+def test_sharded_chunk_names_every_decode_phase_once():
+    """The shard_map'd decode chunk (with chunked prefill) carries every
+    phase scope of core.phases, none nested in another."""
+    from test_phase_scopes import check_scopes, chunk_op_names
+    cfg = configs.smoke("stablelm-1.6b")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    eng = PagedServingEngine(cfg, params, n_max=256, max_batch=2,
+                             block_size=32, chunk_size=4, prefill_budget=8,
+                             mesh_shards=SHARDS)
+    check_scopes(chunk_op_names(eng), fill=True)
