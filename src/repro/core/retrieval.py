@@ -170,49 +170,74 @@ def select_candidates(scores: jax.Array, num_candidates: int) -> jax.Array:
     """Top-C by integer collision score, deterministic index-order ties.
 
     Reference semantics; the production path (`select_candidates_bucket`)
-    and the Pallas bucket_topk kernel implement the paper's histogram/
-    threshold selection with identical index sets.
+    and the Pallas bucket_topk kernel implement the paper's threshold
+    selection with identical index sets.
     """
     _, idx = jax.lax.top_k(scores, num_candidates)
     return idx.astype(jnp.int32)
 
 
+LANES = 128   # keys per block of the candidate compaction: the lane width
+
+
 def select_candidates_bucket(scores: jax.Array, num_candidates: int,
                              score_range: int) -> jax.Array:
-    """O(n) bucket_topk (paper §4.3 kernel i / App. B.2.1) in pure jnp.
+    """O(n) bucket_topk (paper §4.3 kernel i / App. B.2.1) in pure jnp,
+    with no scatter and no sort.
 
-    Small-range integer scores → histogram → threshold walk → prefix-sum
-    compaction. Matches lax.top_k's index set exactly (ties: lowest index
-    first). ~10× cheaper than a sort-based top-k at 262k keys (paper
-    reports up to 9.4× for its CUDA kernel — same algorithmic win).
-    Supports arbitrary leading batch dims.
+    scores: (..., n) int32 in [-1, score_range] (-1 = invalid), n ≥ C.
+    → (..., C) int32: lax.top_k's index set in ascending index order —
+    every key above the threshold score, then the ties at the threshold,
+    lowest index first, up to the quota.
+
+    1. Threshold: ``count_ge[t] = Σ_i [s_i ≥ t]`` for every score t, one
+       compare-and-reduce (a TPU serialises scatter-add updates, and a
+       histogram's updates all collide in ≤ 98 bins). The
+       threshold is the largest t with ``count_ge[t] ≥ C``; the keys
+       above it, ``count_ge[t + 1]``, are the largest count below C.
+    2. Compaction, inverting the ``take`` mask's prefix sums by search
+       over 128-key blocks: slot j lies in the block whose exclusive and
+       inclusive take counts bracket it (a compare-and-reduce over the
+       n/128 blocks); that block's 128 in-block prefix sums are fetched
+       by a one-hot (C, n/128) @ (n/128, 128) matmul (exact in bf16:
+       one term, values ≤ 128), and the slot's lane is the number of
+       them ≤ its rank within the block. Work O(rows · C · n/128).
+
+    n is padded to a multiple of 128 with keys below every threshold,
+    never counted nor taken. Supports arbitrary leading batch dims.
     """
     k = num_candidates
-    rng = score_range + 2                  # scores may carry -1 (invalid)
-    shifted = (scores + 1).astype(jnp.int32)
-    lead = scores.shape[:-1]
-    n = scores.shape[-1]
+    lead, n = scores.shape[:-1], scores.shape[-1]
+    nblk = -(-n // LANES)
+    s = jnp.pad(scores.reshape((-1, n)).astype(jnp.int32),
+                ((0, 0), (0, nblk * LANES - n)), constant_values=-2)
+    rows = s.shape[0]
 
-    def one(s_row):
-        hist = jnp.zeros((rng,), jnp.int32).at[s_row].add(1)
-        desc = hist[::-1]
-        cum = jnp.cumsum(desc)
-        meets = cum >= k
-        thresh = rng - 1 - jnp.argmax(meets)
-        above = jnp.where(meets, 0, desc).sum()
-        quota = k - above
-        take_above = s_row > thresh
-        is_tie = s_row == thresh
-        tie_rank = jnp.cumsum(is_tie.astype(jnp.int32)) - 1
-        take = take_above | (is_tie & (tie_rank < quota))
-        dest = jnp.cumsum(take.astype(jnp.int32)) - 1
-        out = jnp.zeros((k,), jnp.int32)
-        return out.at[jnp.where(take, dest, k)].set(
-            jnp.arange(n, dtype=jnp.int32), mode="drop")
+    t = jnp.arange(-1, score_range + 1, dtype=jnp.int32)
+    count_ge = jnp.sum(s[:, None, :] >= t[:, None], axis=-1,
+                       dtype=jnp.int32)              # (rows, score_range + 2)
+    thresh = jnp.sum(count_ge >= k, axis=-1, dtype=jnp.int32)[:, None] - 2
+    above = jnp.max(jnp.where(count_ge < k, count_ge, 0), axis=-1)
+    quota = (k - above)[:, None]
+    is_tie = s == thresh
+    tie_rank = jnp.cumsum(is_tie.astype(jnp.int32), axis=-1) - 1
+    take = (s > thresh) | (is_tie & (tie_rank < quota))
 
-    flat = shifted.reshape((-1, n))
-    res = jax.vmap(one)(flat)
-    return res.reshape(lead + (k,))
+    in_incl = jnp.cumsum(take.reshape(rows, nblk, LANES).astype(jnp.int32),
+                         axis=-1)                                # (rows, nblk, 128)
+    blk_cnt = in_incl[..., -1]
+    blk_incl = jnp.cumsum(blk_cnt, axis=-1)                      # (rows, nblk)
+    j = jnp.arange(k, dtype=jnp.int32)
+    before = blk_incl[:, None, :] <= j[:, None]                  # (rows, C, nblk)
+    blk = jnp.sum(before, axis=-1, dtype=jnp.int32)
+    rank = j - jnp.sum(jnp.where(before, blk_cnt[:, None, :], 0), axis=-1,
+                       dtype=jnp.int32)
+    onehot = (blk[..., None] == jnp.arange(nblk, dtype=jnp.int32))
+    row = jnp.einsum("rcb,rbl->rcl", onehot.astype(jnp.bfloat16),
+                     in_incl.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    lane = jnp.sum(row <= rank[..., None], axis=-1, dtype=jnp.int32)
+    return (blk * LANES + lane).reshape(lead + (k,))
 
 
 def rerank(meta: KeyMetadata, qt: QueryTransform, cand_idx: jax.Array,
@@ -246,8 +271,9 @@ def retrieve(meta: KeyMetadata, qt: QueryTransform, valid: jax.Array,
              ) -> RetrievalResult:
     """Full two-stage pipeline (Algorithm 1). Shapes broadcast as above.
 
-    bucket_select: use the O(n) histogram/threshold Top-β (paper's
-    bucket_topk) instead of a sort-based top-k — identical index sets.
+    bucket_select: use the O(n) threshold Top-β (paper's bucket_topk,
+    ``select_candidates_bucket``) instead of a sort-based top-k —
+    identical index sets.
     """
     coarse = collision_scores(meta.centroid_ids, qt.q_sub, valid, cfg,
                               hist_sample=hist_sample)

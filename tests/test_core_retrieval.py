@@ -115,6 +115,56 @@ def test_collision_score_is_sum_of_tier_bonuses():
     np.testing.assert_array_equal(got, want)
 
 
+# ------------------------------------------------- candidate selection ----
+def _collision_like(rs, shape, score_range, valid):
+    """Sums of tier weights (most keys score low) with the tail past
+    ``valid`` keys invalid (-1), like Stage I's scores."""
+    B = score_range // 6
+    s = rs.choice(7, size=shape + (B,),
+                  p=[.55, .15, .1, .08, .06, .04, .02]).sum(-1)
+    s[..., valid:] = -1
+    return s
+
+
+def _select_case(name):
+    rs = np.random.default_rng(sum(map(ord, name)))
+    if name == "qwen2":         # (b, G, Hg, n) at the Qwen2 cell's widths
+        return _collision_like(rs, (1, 2, 6, 32768), 96, 24576), 2622, 96
+    if name == "stablelm":      # 4 slots x 32 heads, the StableLM cell's
+        return _collision_like(rs, (4, 32, 1, 2048), 48, 1500), 164, 48
+    if name == "n_not_multiple_of_128":
+        return rs.integers(-1, 21, (3, 1000)), 100, 20
+    if name == "all_invalid":
+        return np.full((2, 3, 640), -1), 64, 20
+    if name == "fewer_valid_than_c":
+        s = np.full((3, 1000), -1)
+        s[:, 7::20] = rs.integers(0, 21, (3, 50))
+        return s, 100, 20
+    if name == "c_equals_n":
+        return rs.integers(-1, 21, (3, 300)), 300, 20
+    if name == "tie_mass":      # 80% of keys tie, the threshold among them
+        s = rs.integers(-1, 21, (3, 5000))
+        s[rs.random(s.shape) < 0.8] = 5
+        return s, 1000, 20
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", [
+    "qwen2", "stablelm", "n_not_multiple_of_128", "all_invalid",
+    "fewer_valid_than_c", "c_equals_n", "tie_mass"])
+def test_select_candidates_bucket_is_topk_index_set(case):
+    """The scatter-free selection returns lax.top_k's index set in
+    ascending index order: all keys above the threshold, then the
+    lowest-index ties up to C."""
+    scores, C, score_range = _select_case(case)
+    scores = jnp.asarray(scores, jnp.int32)
+    got = jax.jit(R.select_candidates_bucket, static_argnums=(1, 2))(
+        scores, C, score_range)
+    want = np.sort(np.asarray(jax.lax.top_k(scores, C)[1]), axis=-1)
+    assert got.dtype == jnp.int32 and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 # ------------------------------------------------------ end-to-end ----------
 @pytest.mark.parametrize("n", [1024, 4096])
 def test_retrieval_recall_beats_random(n):

@@ -5,13 +5,16 @@ shapes, Mosaic's supported ops, VMEM limits) are checked here at the
 widths the serving path runs: Qwen2-1.5B (2 KV heads × 6 query heads,
 head_dim 128 → 16 subspaces) and StableLM-1.6B (32 × 1, head_dim 64 → 8
 subspaces), block 128, an 8192-token context, 4 rows. Interpret mode
-hides every one of these rules, so each test asserts that the compiled
-program really contains the kernel (``tpu_custom_call``).
+hides every one of these rules, so each kernel test asserts that the
+compiled program really contains the kernel (``tpu_custom_call``). The
+candidate selection is plain XLA: its test asserts that the chip's
+program holds no scatter and no sort.
 
 The topology is described inside a fixture (never at import): only the
 test process that runs this file loads the TPU compiler library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,11 +54,11 @@ def compile_tpu(one_chip, monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
 
-    def run(fn, *shapes):
+    def run(fn, *shapes, kernel=True):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in shapes]
         text = jax.jit(fn).lower(*args).compile().as_text()
-        assert "tpu_custom_call" in text
+        assert ("tpu_custom_call" in text) == kernel
         return text
 
     yield run
@@ -140,3 +143,26 @@ def test_bucket_topk_kernel_compiles(compile_tpu):
     score_range = max(configs.get("qwen2-1.5b").pariskv.tier_weights) * w["B"]
     compile_tpu(lambda s: bucket_topk(s, w["cand"], score_range=score_range),
                 ((BATCH, N_MAX), jnp.int32))
+
+
+# the benchmark cells' (b, G, Hg, n) Stage-I scores
+SELECT_SHAPES = {"qwen2-1.5b": (1, 2, 6, 32768),
+                 "stablelm-1.6b": (4, 32, 1, 2048)}
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_select_candidates_compiles_without_scatter_or_sort(compile_tpu, arch):
+    """Candidate selection is plain XLA on the chip, and neither its
+    histogram nor its compaction may become a scatter (the TPU serialises
+    the updates) or a sort."""
+    from repro.core.retrieval import select_candidates_bucket
+    cfg = configs.get(arch)
+    pcfg = cfg.pariskv
+    shape = SELECT_SHAPES[arch]
+    C = pcfg.candidate_count(shape[-1])
+    score_range = max(pcfg.tier_weights) * pcfg.num_subspaces(cfg.head_dim)
+    text = compile_tpu(
+        lambda s: select_candidates_bucket(s, C, score_range),
+        (shape, jnp.int32), kernel=False)
+    # opcodes only: the text also names the test's own function
+    assert not re.findall(r"\s(scatter|sort)\(", text)
