@@ -2,15 +2,17 @@
 
 Once the window has closed, a sample of the served requests, drawn from
 the seed and always holding the longest, is run through the plain
-float32 reference (``bench.reference``) with its served tokens fed back.
-At each served position the reading is the gap by which the served
-token's reference logit lies below the reference's best logit there (0
-where the served token is the reference's argmax). Three numbers are
-read: the widest gap (``logit_gap``), the widest gap of the first tokens
-(made by the prompt's fill, ``first_token_gap``) and the mean gap
-(``mean_logit_gap``). The cell file's ``correctness.limits`` names the
-numbers compared and their limits, which ``PERF.md`` derives from the
-program's readings and the float8 control's.
+float32 reference of the configuration's architecture file
+(``bench/archs/<arch>.py``, built on ``bench.reference``) with its
+served tokens fed back. At each served position the reading is the gap
+by which the served token's reference logit lies below the reference's
+best logit there (0 where the served token is the reference's argmax).
+Three numbers are read: the widest gap (``logit_gap``), the widest gap
+of the first tokens (made by the prompt's fill, ``first_token_gap``)
+and the mean gap (``mean_logit_gap``). The cell file's
+``correctness.limits`` names the numbers compared and their limits,
+which ``PERF.md`` derives from the program's readings and the float8
+control's.
 """
 from __future__ import annotations
 
@@ -69,25 +71,26 @@ def readings(run, reqs, seed: int, control: bool = False) -> dict:
     if not reqs:
         out.update(served=_stats([]), control=_stats([]))
         return out
-    pk = run.cell.config["pariskv"]
-    mc = R.model_dims(run.mc)
+    arch, pk = run.arch, run.cell.config["pariskv"]
+    dims = arch.dims(run.mc)
     n_max = run.cell.cell["engine"]["n_max"]
-    params = weights.make(run.mc, seed)
+    params = weights.make(arch.layout(run.mc), run.mc, seed)
     seq_rows, dec_rows = R.shapes_for(reqs)
-    layer = R.make_layer(mc, pk, n_max, lo=False)
-    layer_lo = R.make_layer(mc, pk, n_max, lo=True) if control else None
+    layer = arch.make_layer(dims, pk, n_max, lo=False)
+    layer_lo = (arch.make_layer(dims, pk, n_max, lo=True) if control
+                else None)
     served, ctrl = [], []
     for prompt, toks in reqs:
-        ref = R.request_logits(params, mc, pk, prompt, toks, seq_rows,
-                               dec_rows, layer)
+        ref = arch.request_logits(params, dims, pk, prompt, toks, seq_rows,
+                                  dec_rows, layer)
         g = R.gaps(ref, toks)
         served.append(g)
         line = (f"[check] prompt {len(prompt)} served {len(toks)}: gap "
                 f"max {g.max():.6f} first {g[0]:.6f} mean {g.mean():.6f}, "
                 f"{int((g > 0).sum())} tokens not the reference's argmax")
         if control:
-            lo = R.request_logits(params, mc, pk, prompt, toks, seq_rows,
-                                  dec_rows, layer_lo, lo=True)
+            lo = arch.request_logits(params, dims, pk, prompt, toks,
+                                     seq_rows, dec_rows, layer_lo, lo=True)
             cg = R.gaps(ref, np.asarray(jax.numpy.argmax(lo, -1)))
             ctrl.append(cg)
             line += (f"; control gap max {cg.max():.6f} first "

@@ -1,16 +1,19 @@
 """The benchmark's own counts of the algorithm's work, from the
 configuration and the cell's shapes — never from how the program does it,
 so a kernel that does the same work another way is held to the same
-number.
+number. What depends on the model comes from its architecture file
+(``arch``, ``bench/archs/<arch>.py``); ``mc`` is the program config.
 
-* Model FLOPs per token: 2 × matrix parameters (LM head included), for
-  prompt and output tokens alike, plus attention, 4 · heads · head_dim
-  per attended key and layer: the causal prefix for a prompt token, the
-  sink, the window and ``top_k`` retrieved keys for a decoded one.
-* Stage I per decoded token: the centroid ids of every key of the
-  retrieval region (kv heads × subspaces bytes each) plus the tier-weight
-  table (int32 per query head, subspace and bucket); one lookup-add per
-  key, query head and subspace.
+* Model FLOPs per token: 2 × ``arch.matrix_params`` (matrix parameters
+  active per token, LM head included), for prompt and output tokens
+  alike, plus ``arch.attn_flops_per_key`` per attended key: the causal
+  prefix for a prompt token, the sink, the window and ``top_k``
+  retrieved keys for a decoded one.
+* Stage I per decoded token, over the ``arch.retrieval`` geometry (kv
+  heads, query heads, head dim, layers): the centroid ids of every key
+  of the retrieval region (kv heads × subspaces bytes each) plus the
+  tier-weight table (int32 per query head, subspace and bucket); one
+  lookup-add per key, query head and subspace.
 * Stage II per decoded token: the 4-bit codes (uint32) and weights
   (float32) of the C candidates, per query head and subspace; one inner
   product over the padded head dim per candidate and query head. C is
@@ -22,22 +25,12 @@ from __future__ import annotations
 from bench.reference import candidate_count, decode_region_ends
 
 
-def matrix_params(mc) -> int:
-    d, f, v = mc.d_model, mc.d_ff, mc.vocab_size
-    H, G, hd = mc.num_heads, mc.num_kv_heads, mc.head_dim
-    per_layer = d * (H + 2 * G) * hd + H * hd * d + 3 * d * f
-    return mc.num_layers * per_layer + d * v
-
-
-def _attn(mc, keys: int) -> int:
-    return 4 * mc.num_heads * mc.head_dim * keys * mc.num_layers
-
-
-def prompt_flops(mc, f0: int, f1: int) -> int:
+def prompt_flops(arch, mc, f0: int, f1: int) -> int:
     """Prompt positions f0..f1-1 (each attends to p + 1 keys)."""
     n = f1 - f0
     keys = (f0 + 1 + f1) * n // 2
-    return 2 * matrix_params(mc) * n + _attn(mc, keys)
+    return (2 * arch.matrix_params(mc) * n
+            + arch.attn_flops_per_key(mc) * keys)
 
 
 def decode_keys(pk: dict, p: int, enc: int) -> int:
@@ -59,30 +52,34 @@ def decoded(pk: dict, prompt_len: int, first: int, last: int):
     return [(prompt_len + j - 1, int(ends[j - 1])) for j in range(lo, last)]
 
 
-def decode_flops(mc, pk: dict, prompt_len: int, first: int, last: int) -> int:
+def decode_flops(arch, mc, pk: dict, prompt_len: int, first: int,
+                 last: int) -> int:
     toks = decoded(pk, prompt_len, first, last)
-    return (2 * matrix_params(mc) * len(toks)
-            + sum(_attn(mc, decode_keys(pk, p, e)) for p, e in toks))
+    per_key = arch.attn_flops_per_key(mc)
+    return (2 * arch.matrix_params(mc) * len(toks)
+            + sum(per_key * decode_keys(pk, p, e) for p, e in toks))
 
 
-def stage1(mc, pk: dict, enc: int) -> tuple:
+def stage1(arch, mc, pk: dict, enc: int) -> tuple:
     """(bytes, ops) of Stage I for one decoded token, over all layers."""
-    B = (1 << (mc.head_dim - 1).bit_length()) // pk["m"]
+    G, H, hd, layers = arch.retrieval(mc)
+    B = (1 << (hd - 1).bit_length()) // pk["m"]
     n = max(enc - pk["sink_size"], 0)
-    table = mc.num_heads * B * (1 << pk["m"]) * 4
-    byts = n * mc.num_kv_heads * B + table
-    return byts * mc.num_layers, n * mc.num_heads * B * mc.num_layers
+    table = H * B * (1 << pk["m"]) * 4
+    byts = n * G * B + table
+    return byts * layers, n * H * B * layers
 
 
-def stage2(mc, pk: dict, enc: int) -> tuple:
+def stage2(arch, mc, pk: dict, enc: int) -> tuple:
     """(bytes, flops) of Stage II for one decoded token, over all layers:
     C candidates for a retrieval region of ``enc - sink`` keys."""
-    dp = 1 << (mc.head_dim - 1).bit_length()
+    _, H, hd, layers = arch.retrieval(mc)
+    dp = 1 << (hd - 1).bit_length()
     B = dp // pk["m"]
     n = max(enc - pk["sink_size"], 0)
     C = candidate_count(pk, n) if n else 0
-    byts = C * mc.num_heads * B * (4 + 4)
-    return byts * mc.num_layers, 2 * C * mc.num_heads * dp * mc.num_layers
+    byts = C * H * B * (4 + 4)
+    return byts * layers, 2 * C * H * dp * layers
 
 
 def roofline_time(byts: float, ops: float, peak: dict) -> tuple:
@@ -101,7 +98,7 @@ def _decoded_in(rec, a: float, b: float) -> tuple:
 
 def traced_work(run) -> dict:
     """The algorithm's work done by the chunks of the traced window."""
-    mc, pk = run.mc, run.cell.config["pariskv"]
+    arch, mc, pk = run.arch, run.mc, run.cell.config["pariskv"]
     f0s, f1s = run.filled.get("tt0", {}), run.filled.get("tt1", {})
     flops = 0
     s1 = [0, 0]
@@ -111,15 +108,16 @@ def traced_work(run) -> dict:
         if rec.req is None:
             continue
         L = len(rec.prompt)
-        flops += prompt_flops(mc, f0s.get(rec.uid, 0), f1s.get(rec.uid, 0))
+        flops += prompt_flops(arch, mc, f0s.get(rec.uid, 0),
+                              f1s.get(rec.uid, 0))
         first, last = _decoded_in(rec, run.tt0, run.tt1)
-        flops += decode_flops(mc, pk, L, first, last)
+        flops += decode_flops(arch, mc, pk, L, first, last)
         for _, enc in decoded(pk, L, first, last):
             decode_tokens += 1
-            b, o = stage1(mc, pk, enc)
+            b, o = stage1(arch, mc, pk, enc)
             s1[0] += b
             s1[1] += o
-            b, o = stage2(mc, pk, enc)
+            b, o = stage2(arch, mc, pk, enc)
             s2[0] += b
             s2[1] += o
     return {"flops": flops, "stage1": tuple(s1), "stage2": tuple(s2),

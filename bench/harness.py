@@ -4,10 +4,13 @@ tokens against the plain reference, print the result line.
 
 Everything that belongs to one configuration, traffic mix, cell or metric
 lives in a file of its own, found by the name ``BENCHMARK.json`` gives it:
-``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json`` (its
-``generator`` names ``bench/generators/<generator>.py``),
-``bench/workloads/<cell>.json`` and ``bench/metrics/<metric>.py`` (or
-the reader of the metric's stem, see ``metric_file``).
+``bench/configs/<config>.json`` (its ``arch`` names
+``bench/archs/<arch>.py``, ``dense`` where it names none: the model's
+program config, weight layout, reference forward and work counts),
+``bench/traffic/<traffic>.json`` (its ``generator`` names
+``bench/generators/<generator>.py``), ``bench/workloads/<cell>.json`` and
+``bench/metrics/<metric>.py`` (or the reader of the metric's stem, see
+``metric_file``).
 """
 from __future__ import annotations
 
@@ -81,31 +84,13 @@ def load_cell(root: Path, name: str) -> Cell:
                 e2e, per_layer, bench["run_seconds"])
 
 
-def model_config(conf: dict):
-    """The program's ModelConfig for a config file: its own entry with the
-    file's numbers put in (so the file is what runs)."""
-    from repro import configs
-    from repro.core.config import ParisKVConfig
-    base = configs.get(conf["program_config"])
-    if base.family != "dense":
-        raise BenchError(f"{conf['name']}: the reference covers dense "
-                         f"attention models only")
-    if conf.get("partial_rotary_factor", 1.0) != 1.0 \
-            or conf.get("qk_layernorm", False):
-        raise BenchError(f"{conf['name']}: the program rotates whole heads "
-                         f"and has no q/k norm")
-    pk = {k: tuple(v) if isinstance(v, list) else v
-          for k, v in conf["pariskv"].items()}
-    return dataclasses.replace(
-        base, num_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"], num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=conf["tie_word_embeddings"],
-        qkv_bias=conf["use_qkv_bias"],
-        norm_eps=conf.get("rms_norm_eps", conf.get("layer_norm_eps")),
-        dtype=conf["torch_dtype"], pariskv=ParisKVConfig(**pk))
+def load_arch(root: Path, conf: dict):
+    """The module of the configuration's architecture,
+    ``bench/archs/<arch>.py`` (``dense`` where the file names none)."""
+    rel = f"bench/archs/{conf.get('arch', 'dense')}.py"
+    if not (root / rel).is_file():
+        raise BenchError(f"{conf['name']}: no {rel} for its arch")
+    return load_module(root / rel)
 
 
 # ------------------------------------------------------------- recording ----
@@ -133,6 +118,7 @@ class Rec:
 class Run:
     """What a metric reader gets."""
     cell: Cell
+    arch: object                   # the module bench/archs/<arch>.py
     mc: object                     # program ModelConfig
     peaks: dict
     device_kind: str
@@ -387,6 +373,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     readings: for ``bench/calibrate.py`` only."""
     out = out or sys.stdout
     cell = load_cell(root, workload)
+    arch = load_arch(root, cell.config)
     if traffic is not None:
         cell.traffic = traffic
     peaks = _json(root / "bench" / "peaks.json")
@@ -404,24 +391,23 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     dev, devs = _device(cell, require_tpu, peaks)
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    mc = model_config(cell.config)
-    weights.check_layout(mc, jax.eval_shape(
+    mc = arch.program_config(cell.config)
+    lay = arch.layout(mc)
+    weights.check_layout(lay, mc, jax.eval_shape(
         lambda: M.init_params(mc, jax.random.PRNGKey(0))))
     e = cell.cell["engine"]
     print(f"[cell] {workload}: config {cell.config['name']} "
-          f"({mc.num_layers}x{mc.d_model}, heads {mc.num_heads}/"
-          f"{mc.num_kv_heads}, head_dim {mc.head_dim}, ffn {mc.d_ff}, "
-          f"vocab {mc.vocab_size}), engine {json.dumps(e)}, traffic "
+          f"({arch.describe(mc)}), engine {json.dumps(e)}, traffic "
           f"{cell.entry['traffic']}, seed {seed}, seconds {seconds}, "
           f"trace {int(trace)}", file=out, flush=True)
 
-    params = weights.make(mc, seed)
+    params = weights.make(lay, mc, seed)
     engine = _engine(cell, mc, params)
     gen = load_module(root / "bench" / "generators"
                       / f"{cell.traffic['generator']}.py")
     plan = gen.generate(cell.traffic, vocab_size=mc.vocab_size, seed=seed,
                         seconds=seconds)
-    run = Run(cell, mc, peaks, dev.device_kind, seconds, t_process)
+    run = Run(cell, arch, mc, peaks, dev.device_kind, seconds, t_process)
     engine.start()
     _warm_up(engine, cell, mc.vocab_size)
     plan = _setup_sessions(engine, run, plan)

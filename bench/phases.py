@@ -155,7 +155,8 @@ def chunk_text(run) -> str:
     from shapes alone."""
     import jax
     from bench import harness, weights
-    params = jax.eval_shape(lambda: weights.make(run.mc, 0))
+    params = jax.eval_shape(
+        lambda: weights.make(run.arch.layout(run.mc), run.mc, 0))
     engine = harness._engine(run.cell, run.mc, params)
     # the state the chunk is compiled for, as shapes: no device memory
     engine._state = jax.eval_shape(engine._init_state)

@@ -1,8 +1,8 @@
-"""Plain reference of what a served request computes, in float32.
-
-It follows the layer equations of the configuration as served (see the
-config file's ``departures``) and ParisKV's decode as the paper and the
-configuration's ``pariskv`` group define it:
+"""Plain reference of ParisKV decode, in float32: the library that the
+architecture files (``bench/archs/<arch>.py``) build their reference
+forward from. An arch file holds its layer equations as served (see the
+config file's ``departures``); what is here is ParisKV's decode as the
+paper and the configuration's ``pariskv`` group define it:
 
 * prompt positions attend causally to every earlier position (the
   chunked fill is a prefill done in pieces);
@@ -20,13 +20,14 @@ configuration's ``pariskv`` group define it:
 
 Nothing here imports the program or reads an array it made: weights come
 from ``bench.weights`` and the same seed, the rotation signs and the code
-levels are worked out here. The computation runs layer by layer, one
-request at a time, with matrix products at ``highest`` precision.
+levels are worked out here. An arch file runs the computation layer by
+layer, one request at a time, with matrix products at ``highest``
+precision.
 
-``precision="fp8"`` is the control: every matrix product's operands, and
-the queries, keys and values, are rounded to float8 e4m3 with a scale
-per row (weights: per output column) — the step below the bfloat16 the
-configuration states.
+``mm(..., lo=True)`` and ``round8`` are the control: every matrix
+product's operands, and the queries, keys and values, rounded to float8
+e4m3 with a scale per row (weights: per output column) — the step below
+the bfloat16 the configuration states.
 """
 from __future__ import annotations
 
@@ -97,24 +98,24 @@ def decode_region_ends(pk: dict, prompt_len: int, n_decode: int) -> np.ndarray:
 
 
 # --------------------------------------------------------------- pieces ----
-def _round8(x, axis):
+def round8(x, axis):
     """Round to float8 e4m3 with one scale per slice along ``axis``."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 448.0
     s = jnp.where(s > 0, s, 1.0)
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(a, w, lo: bool):
+def mm(a, w, lo: bool):
     if lo:
-        a, w = _round8(a, -1), _round8(w, 0)
+        a, w = round8(a, -1), round8(w, 0)
     return a @ w
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     half = x.shape[-1] // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = pos[:, None].astype(jnp.float32) * freqs
@@ -176,9 +177,9 @@ def tier_table(cs, counts, n_valid, pk):
     return jnp.take_along_axis(w_sorted, jnp.argsort(order, -1), -1)
 
 
-# ---------------------------------------------------------------- layer ----
-def _decode_attention(q, k, v, onehot, kt, qpos, enc, *, pk, signs,
-                      scale, n_cand, centroids):
+# ------------------------------------------------------------ attention ----
+def decode_attention(q, k, v, onehot, kt, qpos, enc, *, pk, signs,
+                     scale, n_cand, centroids):
     """ParisKV attention of decoded positions. q (T, G, Hg, hd) at
     positions ``qpos`` (T,) with region ends ``enc`` (T,); k, v (S, G, hd);
     onehot (S, G, B, 2^m) bucket of every key; kt (S, G, B·m)."""
@@ -219,7 +220,7 @@ def _decode_attention(q, k, v, onehot, kt, qpos, enc, *, pk, signs,
     return jnp.einsum("tghs,sgd->tghd", jax.nn.softmax(s, -1), v)
 
 
-def _causal_attention(q, k, v, *, scale):
+def causal_attention(q, k, v, *, scale):
     """Causal attention of every row q (S, G, Hg, hd) at positions 0..S-1
     against k, v (S, G, hd), in blocks of 512 rows."""
     S = k.shape[0]
@@ -236,101 +237,13 @@ def _causal_attention(q, k, v, *, scale):
     return jax.lax.map(one, jnp.arange(S // blk)).reshape(q.shape)
 
 
-def make_layer(mc: dict, pk: dict, n_max: int, lo: bool):
-    """One jitted layer over a whole request, x (S, d) f32 → x, S a multiple
-    of 512. Rows at ``dec_pos`` (T,) are decoded positions with region
-    ends ``dec_enc``; every other row attends causally (prompt rows; rows
-    past the request are padding and are never read)."""
-    H, G, hd = mc["heads"], mc["kv_heads"], mc["head_dim"]
-    Hg = H // G
-    signs = jnp.asarray(rotation_signs(1 << (hd - 1).bit_length(),
-                                       pk["srht_seed"]))
-    th, lv = magnitude_levels(pk["m"], pk["magnitude_bits"])
-    m = pk["m"]
-    bits = (np.arange(1 << m)[:, None] >> np.arange(m)[None]) & 1
-    centroids = jnp.asarray((bits * 2.0 - 1.0) / np.sqrt(m), jnp.float32)
-    n_cand = candidate_count(pk, n_max)
-    scale = 1.0 / math.sqrt(hd)
-
-    @jax.jit
-    def layer(x, w, dec_pos, dec_enc):
-        S = x.shape[0]
-        pos = jnp.arange(S)
-        h = _rms(x, w["norm_attn"], mc["eps"])
-        q, k, v = (_mm(h, w["wq"], lo), _mm(h, w["wk"], lo),
-                   _mm(h, w["wv"], lo))
-        if "bq" in w:
-            q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
-        q = _rope(q.reshape(S, H, hd), pos, mc["theta"])
-        k = _rope(k.reshape(S, G, hd), pos, mc["theta"])
-        v = v.reshape(S, G, hd)
-        if lo:
-            q, k, v = _round8(q, -1), _round8(k, -1), _round8(v, -1)
-        qg = q.reshape(S, G, Hg, hd)
-        att = _causal_attention(qg, k, v, scale=scale)
-        ids, kt = key_metadata(k, signs, pk, th, lv)
-        onehot = jax.nn.one_hot(ids, 1 << m, dtype=jnp.bfloat16)
-        T = dec_pos.shape[0]
-        tb = 32
-
-        def dec_block(i):
-            sl = lambda a: jax.lax.dynamic_slice_in_dim(a, i * tb, tb, 0)
-            return _decode_attention(qg[sl(dec_pos)], k, v, onehot, kt,
-                                     sl(dec_pos), sl(dec_enc), pk=pk,
-                                     signs=signs, scale=scale,
-                                     n_cand=n_cand, centroids=centroids)
-
-        out_d = jax.lax.map(dec_block, jnp.arange(T // tb))
-        att = att.at[dec_pos].set(out_d.reshape((T, G, Hg, hd)))
-        x = x + _mm(att.reshape(S, H * hd), w["wo"], lo)
-        h = _rms(x, w["norm_mlp"], mc["eps"])
-        y = jax.nn.silu(_mm(h, w["wi_gate"], lo)) * _mm(h, w["wi_up"], lo)
-        return x + _mm(y, w["wo_mlp"], lo)
-
-    return layer
-
-
-def model_dims(mcfg) -> dict:
-    return dict(heads=mcfg.num_heads, kv_heads=mcfg.num_kv_heads,
-                head_dim=mcfg.head_dim, eps=mcfg.norm_eps,
-                theta=mcfg.rope_theta, layers=mcfg.num_layers,
-                tied=mcfg.tie_embeddings)
-
-
+# ------------------------------------------------------------ requests ----
 def shapes_for(requests):
     """(sequence rows, decoded rows) that hold every (prompt, served) pair
     of ``requests``, so one compile serves them all."""
     n_dec = -(-max(max(len(s) - 1 for _, s in requests), 1) // 256) * 256
     seq = max(len(p) for p, _ in requests) + n_dec
     return -(-seq // 512) * 512, n_dec
-
-
-def request_logits(params, mc: dict, pk: dict, prompt: np.ndarray,
-                   served: np.ndarray, seq_rows: int, dec_rows: int,
-                   layer, lo: bool = False) -> jax.Array:
-    """Logits (T, V) at the T positions that produced the served tokens:
-    the last prompt position, then every decoded position, with the
-    served tokens fed back (teacher forcing). ``layer`` comes from
-    ``make_layer`` (with the same ``lo``); ``seq_rows``/``dec_rows`` from
-    ``shapes_for``."""
-    L, T = len(prompt), len(served)
-    toks = np.zeros(seq_rows, np.int32)
-    toks[:L + T - 1] = np.concatenate([prompt, served[:-1]])
-    dec_pos = (L + np.arange(dec_rows)).astype(np.int32)
-    dec_enc = decode_region_ends(pk, L, dec_rows)
-    f32 = lambda a: jnp.asarray(a, jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        x = f32(params["embed"][jnp.asarray(toks)])
-        st = params["stages"][0]["l0"]
-        for li in range(mc["layers"]):
-            w = jax.tree.map(lambda a: f32(a[li]), st)
-            w = {**w["attn"], **w["mlp"], "norm_attn": w["norm_attn"],
-                 "norm_mlp": w["norm_mlp"]}
-            x = layer(x, w, jnp.asarray(dec_pos), jnp.asarray(dec_enc))
-        xo = _rms(x[L - 1:L - 1 + T], f32(params["final_norm"]), mc["eps"])
-        head = (f32(params["embed"]).T if mc["tied"]
-                else f32(params["unembed"]))
-        return _mm(xo, head, lo)
 
 
 def gaps(ref_logits: jax.Array, tokens: np.ndarray) -> np.ndarray:

@@ -111,11 +111,12 @@ def root(tmp_path):
 
 
 def run(root: Root, workload: str, seed: int = 7, seconds: float = 2.0,
-        trace: bool = False, **kw) -> dict:
+        trace: bool = False, out=None, **kw) -> dict:
+    """One run on the CPU; its printed lines go to ``out`` where given."""
     import io
     import time
     from bench.harness import run_cell
-    buf = io.StringIO()
+    buf = out if out is not None else io.StringIO()
     res = run_cell(root.path, workload, seed, seconds, trace,
                    time.perf_counter(), require_tpu=False, out=buf, **kw)
     last = buf.getvalue().strip().split("\n")[-1]

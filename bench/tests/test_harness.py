@@ -1,5 +1,6 @@
 """The driving loop end to end on the CPU, at a tiny size."""
 import hashlib
+import io
 import os
 import subprocess
 import sys
@@ -67,11 +68,29 @@ def _digest(path):
             and "__pycache__" not in p.parts}
 
 
-def test_a_config_cell_generator_and_metric_are_new_files(root):
+# an architecture of its own: the dense arch under another name, with a
+# description of its own
+TINY_ARCH = """
+from bench.archs.dense import *  # noqa: F401,F403
+from bench.archs import dense
+
+
+def describe(mc):
+    return "tiny_arch " + dense.describe(mc)
+"""
+
+
+@pytest.mark.parametrize("arch", [None, "tiny_arch"])
+def test_a_config_cell_generator_and_metric_are_new_files(root, arch):
     """Adding each is a new file plus a new BENCHMARK.json entry: no file
-    under bench/ that was there before changes."""
+    under bench/ that was there before changes. So is a configuration
+    that brings its own architecture file."""
     before = _digest(root.path / "bench")
-    root.write("bench/configs/tiny-new.json", tiny_config("tiny-new"))
+    conf = tiny_config("tiny-new")
+    if arch is not None:
+        root.write(f"bench/archs/{arch}.py", TINY_ARCH)
+        conf["arch"] = arch
+    root.write("bench/configs/tiny-new.json", conf)
     root.bench["configs"].append({"name": "tiny-new", "source": "test",
                                   "file": "bench/configs/tiny-new.json",
                                   "reduced": [], "why": "test"})
@@ -106,7 +125,21 @@ def read(run):
     root.save()
     after = _digest(root.path / "bench")
     assert all(after[k] == v for k, v in before.items())
-    res = run(root, "tiny-new-list", seconds=1.5, trace=True)
+    out = io.StringIO()
+    res = run(root, "tiny-new-list", seconds=1.5, trace=True, out=out)
     assert res["correct"], res["check"]
     assert res["attempted"] == 3
     assert res["metrics"]["tokens_in_window.tiny"]["value"] > 0
+    cell_line = out.getvalue().split("\n")[0]
+    assert cell_line.startswith("[cell] tiny-new-list: config tiny-new (")
+    assert ("(tiny_arch 2x256" in cell_line) == (arch == "tiny_arch")
+
+
+def test_an_unknown_arch_stops_the_run(root):
+    from bench.harness import BenchError
+    conf = tiny_config("tiny-odd")
+    conf["arch"] = "no_such_arch"
+    root.write("bench/configs/tiny-odd.json", conf)
+    root.add_cell("tiny", "tiny-odd", "tiny-closed", tiny_cell(1, 0.2))
+    with pytest.raises(BenchError, match="bench/archs/no_such_arch.py"):
+        run(root, "tiny")

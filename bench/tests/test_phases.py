@@ -157,8 +157,9 @@ def test_chunk_rebuilt_from_shapes_is_the_one_that_ran(root):
     from repro.serving import Request
     root.add_cell("tiny", "tiny-bf16", "tiny-open", tiny_cell(3, 0.2))
     cell = harness.load_cell(root.path, "tiny")
-    mc = harness.model_config(cell.config)
-    engine = harness._engine(cell, mc, weights.make(mc, 7))
+    arch = harness.load_arch(root.path, cell.config)
+    mc = arch.program_config(cell.config)
+    engine = harness._engine(cell, mc, weights.make(arch.layout(mc), mc, 7))
     engine.start()
     harness._warm_up(engine, cell, mc.vocab_size)
     rng = np.random.default_rng(0)
@@ -168,7 +169,8 @@ def test_chunk_rebuilt_from_shapes_is_the_one_that_ran(root):
     while engine.pending():
         engine.step_serve()
     served = engine.lower_chunk().compile().as_text()
-    rebuilt = phases.chunk_text(SimpleNamespace(mc=mc, cell=cell))
+    rebuilt = phases.chunk_text(SimpleNamespace(arch=arch, mc=mc,
+                                                cell=cell))
     assert phases.scope_map(rebuilt) == phases.scope_map(served)
     assert {phases.outermost(n) for n in phases.scope_map(served).values()} \
         >= set(phases.VOCAB)

@@ -1,50 +1,23 @@
 """Random weights from the seed, made by the benchmark and not the program.
 
-The tree is the serving engine's parameter layout for a dense
-attention + gated-MLP model. ``check_layout`` compares it with the
-program's own shapes (``jax.eval_shape`` of its init, no values), so a
-renamed or reshaped leaf stops the run instead of serving garbage. The
-plain reference regenerates the same tree from the same seed; it takes
-no array the program made.
+The tree is the serving engine's parameter layout as the configuration's
+architecture file gives it (``layout(mc)`` of ``bench/archs/<arch>.py``:
+flat ``path -> (shape, kind)``, layers under ``stages/<i>/l<j>/``). Each
+leaf is drawn from the seed and a key of its own path. ``check_layout``
+compares the tree with the program's own shapes (``jax.eval_shape`` of
+its init, no values), so a renamed or reshaped leaf stops the run
+instead of serving garbage. The plain reference regenerates the same
+tree from the same seed; it takes no array the program made.
 """
 from __future__ import annotations
 
 import zlib
-from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 STD = 0.02        # matrices and biases
 NORM_STD = 0.1    # norm scales are 1 + NORM_STD · N(0, 1)
-
-
-def layout(mc) -> Dict[str, Tuple[Tuple[int, ...], str]]:
-    """Flat ``path -> (shape, kind)`` of the served tree; ``kind`` is
-    ``matrix``, ``bias`` or ``norm``. Layer leaves are stacked on a
-    leading axis of ``num_layers``."""
-    d, f, v = mc.d_model, mc.d_ff, mc.vocab_size
-    H, G, hd, R = mc.num_heads, mc.num_kv_heads, mc.head_dim, mc.num_layers
-    lay = {
-        "embed": ((v, d), "matrix"),
-        "final_norm": ((d,), "norm"),
-        "stages/0/l0/norm_attn": ((R, d), "norm"),
-        "stages/0/l0/norm_mlp": ((R, d), "norm"),
-        "stages/0/l0/attn/wq": ((R, d, H * hd), "matrix"),
-        "stages/0/l0/attn/wk": ((R, d, G * hd), "matrix"),
-        "stages/0/l0/attn/wv": ((R, d, G * hd), "matrix"),
-        "stages/0/l0/attn/wo": ((R, H * hd, d), "matrix"),
-        "stages/0/l0/mlp/wi_gate": ((R, d, f), "matrix"),
-        "stages/0/l0/mlp/wi_up": ((R, d, f), "matrix"),
-        "stages/0/l0/mlp/wo_mlp": ((R, f, d), "matrix"),
-    }
-    if mc.qkv_bias:
-        lay["stages/0/l0/attn/bq"] = ((R, H * hd), "bias")
-        lay["stages/0/l0/attn/bk"] = ((R, G * hd), "bias")
-        lay["stages/0/l0/attn/bv"] = ((R, G * hd), "bias")
-    if not mc.tie_embeddings:
-        lay["unembed"] = ((d, v), "matrix")
-    return lay
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -64,6 +37,8 @@ def _leaf(key, path: str, shape, kind: str, dtype):
 
 
 def _nest(flat: dict) -> dict:
+    """The served tree of a flat layout; ``stages`` becomes the list of
+    stages in the order of their index."""
     tree: dict = {}
     for path, val in flat.items():
         node = tree
@@ -71,7 +46,8 @@ def _nest(flat: dict) -> dict:
         for p in parts[:-1]:
             node = node.setdefault(p, {})
         node[parts[-1]] = val
-    tree["stages"] = [tree["stages"]["0"]]
+    stages = tree["stages"]
+    tree["stages"] = [stages[str(i)] for i in range(len(stages))]
     return tree
 
 
@@ -79,25 +55,37 @@ def served_dtype(mc):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[mc.dtype]
 
 
-def make(mc, seed: int) -> dict:
-    """The whole served tree, made on the device in one jitted call, in
-    the type it is served in."""
-    lay = layout(mc)
-    dtype = served_dtype(mc)
+def _specs(lay: dict, mc) -> dict:
+    """``path -> (shape, kind, dtype)``: a layout entry ``(shape, kind)``
+    is made in the type ``mc`` serves, ``(shape, kind, "float32")`` in
+    the type it names (a leaf the program keeps wider, such as a
+    router)."""
+    out = {}
+    for path, (shape, kind, *dt) in lay.items():
+        out[path] = (shape, kind,
+                     jnp.dtype(dt[0]) if dt else served_dtype(mc))
+    return out
+
+
+def make(lay: dict, mc, seed: int) -> dict:
+    """The whole served tree of layout ``lay``, made on the device in one
+    jitted call, in the type each leaf is served in."""
+    specs = _specs(lay, mc)
 
     @jax.jit
     def build(key):
-        return {p: _leaf(key, p, s, k, dtype) for p, (s, k) in lay.items()}
+        return {p: _leaf(key, p, s, k, dt) for p, (s, k, dt) in specs.items()}
 
     return _nest(build(seed_key(seed)))
 
 
-def check_layout(mc, params_shapes) -> None:
-    """Raise unless the benchmark's tree has the program's paths and shapes
-    (``params_shapes``: the program's init under ``jax.eval_shape``)."""
+def check_layout(lay: dict, mc, params_shapes) -> None:
+    """Raise unless the tree of layout ``lay`` has the program's paths,
+    shapes and types (``params_shapes``: the program's init under
+    ``jax.eval_shape``)."""
     ours = jax.tree_util.tree_flatten_with_path(
-        jax.eval_shape(lambda: _nest({p: jnp.zeros(s, served_dtype(mc))
-                                      for p, (s, _) in layout(mc).items()})))
+        jax.eval_shape(lambda: _nest({p: jnp.zeros(s, dt) for p, (s, _, dt)
+                                      in _specs(lay, mc).items()})))
     theirs = jax.tree_util.tree_flatten_with_path(params_shapes)
     a = {jax.tree_util.keystr(p): (l.shape, l.dtype) for p, l in ours[0]}
     b = {jax.tree_util.keystr(p): (l.shape, l.dtype) for p, l in theirs[0]}
