@@ -1,13 +1,19 @@
 """DeepSeek-V2-Lite 16B [arXiv:2405.04434] — MLA + MoE.
 
-MLA: kv_lora_rank 512, qk_nope 128, decoupled rope head 64, v_head 128.
-MoE: 64 routed experts top-6 + 2 shared, moe_d_ff 1408; layer 0 is a dense
-FFN (d_ff 10944). ParisKV retrieves in the shared 576-d latent space
-(DESIGN.md §4 — beyond-paper adaptation).
+MLA: kv_lora_rank 512, qk_nope 128, decoupled rope head 64, v_head 128,
+no q-lora; rope θ 1e4 with YaRN (factor 40 over 4096 original positions,
+β_fast 32, β_slow 1, mscale = mscale_all_dim = 0.707). MoE: 64 routed
+experts top-6 (softmax gates, not renormalised) + 2 shared, moe_d_ff
+1408; layer 0 is a dense FFN (d_ff 10944). ParisKV retrieves in the
+shared 576-d latent space (see ``models/mla.py``'s docstring — a
+beyond-paper adaptation). The published code rotates the rope dims in an
+interleaved order; that is a fixed permutation of the rope columns of
+``wq`` and ``w_dkv``, which random weights absorb, so the program's
+half-split rotation is no departure.
 """
 import dataclasses
 
-from repro.core.config import ModelConfig, ParisKVConfig
+from repro.core.config import ModelConfig, ParisKVConfig, YarnRope
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b", family="moe",
@@ -15,7 +21,12 @@ CONFIG = ModelConfig(
     d_ff=1408, vocab_size=102_400,
     num_experts=64, experts_per_token=6, num_shared_experts=2,
     moe_d_ff=1408, first_dense_layers=1, first_dense_d_ff=10_944,
+    norm_topk_prob=False,
     kv_lora_rank=512, rope_head_dim=64, v_head_dim=128,
+    rope_theta=10_000.0,
+    rope_scaling=YarnRope(factor=40.0, original_max_position_embeddings=4096,
+                          beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                          mscale_all_dim=0.707),
     source="arXiv:2405.04434",
 )
 

@@ -49,9 +49,13 @@ class LayerKVCache(NamedTuple):
     meta_ids:    (b, G, n_max, B) uint8   — Stage-I centroid assignments
     meta_codes:  (b, G, n_max, B) uint32  — Stage-II packed 4-bit codes
     meta_w:      (b, G, n_max, B) float32 — RSQ-IP weights w_{i,b}
+
+    A **latent** cache (MLA) has ``v`` None: its one K row per token
+    (G = 1, hd = the latent width) is read as the value too, so it is
+    stored once. Every helper here writes ``v`` only where it exists.
     """
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     meta_ids: jax.Array
     meta_codes: jax.Array
     meta_w: jax.Array
@@ -82,12 +86,14 @@ def initial_regions(lengths: jax.Array, cfg: ParisKVConfig) -> CacheRegions:
 
 
 def init_layer_cache(batch: int, n_max: int, num_kv_heads: int, head_dim: int,
-                     cfg: ParisKVConfig, dtype=jnp.bfloat16) -> LayerKVCache:
+                     cfg: ParisKVConfig, dtype=jnp.bfloat16,
+                     values: bool = True) -> LayerKVCache:
+    """``values=False`` builds a latent cache (no ``v`` leaf)."""
     B = cfg.num_subspaces(head_dim)
     g = num_kv_heads
     return LayerKVCache(
         k=jnp.zeros((batch, n_max, g, head_dim), dtype),
-        v=jnp.zeros((batch, n_max, g, head_dim), dtype),
+        v=jnp.zeros((batch, n_max, g, head_dim), dtype) if values else None,
         meta_ids=jnp.zeros((batch, g, n_max, B), jnp.uint8),
         meta_codes=jnp.zeros((batch, g, n_max, B), jnp.uint32),
         meta_w=jnp.zeros((batch, g, n_max, B), jnp.float32),
@@ -95,14 +101,15 @@ def init_layer_cache(batch: int, n_max: int, num_kv_heads: int, head_dim: int,
 
 
 def cache_spec(batch: int, n_max: int, num_kv_heads: int, head_dim: int,
-               cfg: ParisKVConfig, dtype=jnp.bfloat16) -> LayerKVCache:
+               cfg: ParisKVConfig, dtype=jnp.bfloat16,
+               values: bool = True) -> LayerKVCache:
     """ShapeDtypeStruct twin of init_layer_cache — used by the dry-run."""
     B = cfg.num_subspaces(head_dim)
     g = num_kv_heads
     sds = jax.ShapeDtypeStruct
     return LayerKVCache(
         k=sds((batch, n_max, g, head_dim), dtype),
-        v=sds((batch, n_max, g, head_dim), dtype),
+        v=sds((batch, n_max, g, head_dim), dtype) if values else None,
         meta_ids=sds((batch, g, n_max, B), jnp.uint8),
         meta_codes=sds((batch, g, n_max, B), jnp.uint32),
         meta_w=sds((batch, g, n_max, B), jnp.float32),
@@ -133,7 +140,8 @@ def prefill_write(cache: LayerKVCache, k_new: jax.Array, v_new: jax.Array,
     b, S = k_new.shape[:2]
     cache = cache._replace(
         k=jax.lax.dynamic_update_slice_in_dim(cache.k, k_new.astype(cache.k.dtype), 0, axis=1),
-        v=jax.lax.dynamic_update_slice_in_dim(cache.v, v_new.astype(cache.v.dtype), 0, axis=1),
+        v=None if cache.v is None else jax.lax.dynamic_update_slice_in_dim(
+            cache.v, v_new.astype(cache.v.dtype), 0, axis=1),
     )
     meta = _encode_block(k_new, cfg, signs)
     cache = cache._replace(
@@ -157,7 +165,8 @@ def decode_append(cache: LayerKVCache, k_t: jax.Array, v_t: jax.Array,
         c, t[None], p, axis=0))
     return cache._replace(
         k=upd(cache.k, k_t.astype(cache.k.dtype), pos),
-        v=upd(cache.v, v_t.astype(cache.v.dtype), pos),
+        v=None if cache.v is None else upd(cache.v, v_t.astype(cache.v.dtype),
+                                           pos),
     )
 
 
@@ -244,8 +253,8 @@ def fill_chunk_write(cache: LayerKVCache, row: jax.Array, start: jax.Array,
     out = cache._replace(
         k=cache.k.at[rows, posn].set(k_chunk.astype(cache.k.dtype),
                                      mode="drop"),
-        v=cache.v.at[rows, posn].set(v_chunk.astype(cache.v.dtype),
-                                     mode="drop"))
+        v=None if cache.v is None else cache.v.at[rows, posn].set(
+            v_chunk.astype(cache.v.dtype), mode="drop"))
     if meta is not None:
         def upd(dst, new):                               # new: (G, P, B)
             return dst.at[rows, :, posn].set(jnp.moveaxis(new, 0, 1),
@@ -275,7 +284,8 @@ def paged_fill_chunk_write(pool: PagedLayerKVCache, bt_row: jax.Array,
     pb = jnp.where(valid & (blk < nblk) & (pb >= 0), pb, nb)  # OOB → drop
     out = pool._replace(
         k=pool.k.at[pb, off].set(k_chunk.astype(pool.k.dtype), mode="drop"),
-        v=pool.v.at[pb, off].set(v_chunk.astype(pool.v.dtype), mode="drop"))
+        v=None if pool.v is None else pool.v.at[pb, off].set(
+            v_chunk.astype(pool.v.dtype), mode="drop"))
     if meta is not None:
         def upd(dst, new):                               # new: (G, P, B)
             return dst.at[pb, :, off].set(jnp.moveaxis(new, 0, 1),
@@ -386,9 +396,11 @@ class PagedLayerKVCache(NamedTuple):
     meta_ids:    (num_blocks, G, block_size, B) uint8
     meta_codes:  (num_blocks, G, block_size, B) uint32
     meta_w:      (num_blocks, G, block_size, B) float32
+
+    ``v`` None: a latent pool (MLA), as for :class:`LayerKVCache`.
     """
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     meta_ids: jax.Array
     meta_codes: jax.Array
     meta_w: jax.Array
@@ -396,12 +408,15 @@ class PagedLayerKVCache(NamedTuple):
 
 def init_paged_cache(num_blocks: int, block_size: int, num_kv_heads: int,
                      head_dim: int, cfg: ParisKVConfig,
-                     dtype=jnp.bfloat16) -> PagedLayerKVCache:
+                     dtype=jnp.bfloat16,
+                     values: bool = True) -> PagedLayerKVCache:
+    """``values=False`` builds a latent pool (no ``v`` leaf)."""
     B = cfg.num_subspaces(head_dim)
     g = num_kv_heads
     return PagedLayerKVCache(
         k=jnp.zeros((num_blocks, block_size, g, head_dim), dtype),
-        v=jnp.zeros((num_blocks, block_size, g, head_dim), dtype),
+        v=(jnp.zeros((num_blocks, block_size, g, head_dim), dtype)
+           if values else None),
         meta_ids=jnp.zeros((num_blocks, g, block_size, B), jnp.uint8),
         meta_codes=jnp.zeros((num_blocks, g, block_size, B), jnp.uint32),
         meta_w=jnp.zeros((num_blocks, g, block_size, B), jnp.float32),
@@ -410,13 +425,14 @@ def init_paged_cache(num_blocks: int, block_size: int, num_kv_heads: int,
 
 def paged_cache_spec(num_blocks: int, block_size: int, num_kv_heads: int,
                      head_dim: int, cfg: ParisKVConfig,
-                     dtype=jnp.bfloat16) -> PagedLayerKVCache:
+                     dtype=jnp.bfloat16,
+                     values: bool = True) -> PagedLayerKVCache:
     B = cfg.num_subspaces(head_dim)
     g = num_kv_heads
     sds = jax.ShapeDtypeStruct
     return PagedLayerKVCache(
         k=sds((num_blocks, block_size, g, head_dim), dtype),
-        v=sds((num_blocks, block_size, g, head_dim), dtype),
+        v=sds((num_blocks, block_size, g, head_dim), dtype) if values else None,
         meta_ids=sds((num_blocks, g, block_size, B), jnp.uint8),
         meta_codes=sds((num_blocks, g, block_size, B), jnp.uint32),
         meta_w=sds((num_blocks, g, block_size, B), jnp.float32),
@@ -483,7 +499,8 @@ def paged_decode_append(pool: PagedLayerKVCache, block_tables: jax.Array,
     pb = jnp.where(pb < 0, nb, pb)          # unallocated → OOB → dropped
     return pool._replace(
         k=pool.k.at[pb, off].set(k_t.astype(pool.k.dtype), mode="drop"),
-        v=pool.v.at[pb, off].set(v_t.astype(pool.v.dtype), mode="drop"),
+        v=None if pool.v is None else pool.v.at[pb, off].set(
+            v_t.astype(pool.v.dtype), mode="drop"),
     )
 
 
@@ -774,7 +791,8 @@ def paged_scatter_prefill(pool: PagedLayerKVCache, cache1: LayerKVCache,
                                           mode="drop")
 
     return PagedLayerKVCache(
-        k=kv(pool.k, cache1.k), v=kv(pool.v, cache1.v),
+        k=kv(pool.k, cache1.k),
+        v=None if pool.v is None else kv(pool.v, cache1.v),
         meta_ids=meta(pool.meta_ids, cache1.meta_ids),
         meta_codes=meta(pool.meta_codes, cache1.meta_codes),
         meta_w=meta(pool.meta_w, cache1.meta_w),
@@ -794,7 +812,8 @@ def paged_clear_blocks(pool: PagedLayerKVCache,
     every surviving holder's prefix."""
     def z(a):
         return a.at[:, phys_blocks].set(0, mode="drop")
-    return PagedLayerKVCache(k=z(pool.k), v=z(pool.v),
+    return PagedLayerKVCache(k=z(pool.k),
+                             v=None if pool.v is None else z(pool.v),
                              meta_ids=z(pool.meta_ids),
                              meta_codes=z(pool.meta_codes),
                              meta_w=z(pool.meta_w))

@@ -15,7 +15,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from dataclasses import dataclass
-from typing import Tuple
+from typing import Optional, Tuple
 
 
 def _next_pow2(n: int) -> int:
@@ -79,6 +79,20 @@ class ParisKVConfig:
 
 
 @dataclass(frozen=True)
+class YarnRope:
+    """YaRN rope scaling (Peng et al. 2023), the published config's
+    ``rope_scaling`` group with ``type: yarn``. The frequencies and the
+    softmax factor it implies are worked out in ``models.layers``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """Architecture definition. Field groups are optional per family."""
 
@@ -95,6 +109,7 @@ class ModelConfig:
 
     # --- attention variants --------------------------------------------------
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnRope] = None  # deepseek-v2 (MLA rope dims)
     qkv_bias: bool = False                 # qwen2
     attn_logit_softcap: float = 0.0        # gemma2 (0 = disabled)
     final_logit_softcap: float = 0.0       # gemma2
@@ -108,6 +123,7 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_d_ff: int = 0
     first_dense_layers: int = 0            # deepseek-v2: layer 0 is dense
+    norm_topk_prob: bool = True            # renormalise the top-k gates
     router_aux_loss_coef: float = 0.001
 
     # --- MLA (deepseek-v2) -------------------------------------------------------

@@ -15,6 +15,7 @@ global layers through ParisKV two-stage retrieval (core.retrieval) and local
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -60,13 +61,51 @@ def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
     return (y * scale + bias).astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: (..., seq, heads, hd); positions: (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1·m·ln s + 1`` (1 at s ≤ 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, ys) -> np.ndarray:
+    """YaRN's per-pair rotary frequencies (``ys``: a config.YarnRope), as
+    the DeepSeek-V2 modeling code builds them: pair ``i`` of ``dim`` keeps
+    its rope frequency below the ``beta_fast`` correction dimension,
+    takes the ``factor``-interpolated one above the ``beta_slow`` one, and
+    ramps linearly in between. float32 (dim // 2,)."""
+    half = dim // 2
+    extra = theta ** (-np.arange(half, dtype=np.float64) * 2 / dim)
+    inter = extra / ys.factor
+    L0 = ys.original_max_position_embeddings
+
+    def corr(beta):
+        return dim * math.log(L0 / (2 * math.pi * beta)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr(ys.beta_fast)), 0)
+    high = min(math.ceil(corr(ys.beta_slow)), dim - 1)
+    if high == low:
+        high += 0.001
+    mask = 1.0 - np.clip((np.arange(half) - low) / (high - low), 0.0, 1.0)
+    return (inter * (1.0 - mask) + extra * mask).astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         scaling=None) -> jax.Array:
+    """Rotary embedding. x: (..., seq, heads, hd); positions: (..., seq).
+    ``scaling`` (a config.YarnRope) switches to YaRN's frequencies, with
+    cos/sin scaled by ``mscale(s, mscale) / mscale(s, mscale_all_dim)``."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(hd, theta, scaling))
     ang = positions[..., None].astype(jnp.float32) * freqs       # (..., seq, half)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if scaling is not None:
+        f = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if f != 1.0:
+            cos, sin = cos * f, sin * f
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)

@@ -230,7 +230,8 @@ def layer_fwd_train(p: dict, x: jax.Array, ld: LayerDef, cfg: ModelConfig,
     if ld.ffn != "none":
         h = L.rms_norm(x, p["norm_mlp"], cfg.norm_eps)
         if ld.ffn == "moe":
-            y, aux = MOE.moe_fwd(p["moe"], h, cfg.experts_per_token)
+            y, aux = MOE.moe_fwd(p["moe"], h, cfg.experts_per_token,
+                                 cfg.norm_topk_prob)
         else:
             y = L.mlp_fwd(p["mlp"], h)
         x = x + y.astype(x.dtype)

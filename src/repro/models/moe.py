@@ -1,12 +1,16 @@
-"""Mixture-of-Experts FFN: top-k routing with capacity + shared experts.
+"""Mixture-of-Experts FFN: dropless top-k routing + shared experts.
 
-Covers grok-1 (8 experts, top-2) and deepseek-v2-lite (64 routed top-6 +
-2 shared). Dispatch is scatter/gather based (Megatron/MaxText-style): token
-ids are scattered into per-expert capacity buffers, experts run dense
-matmuls over their buffers, outputs gather back per (token, slot). Memory
-is O(n·k + E·cap·d) — no (n × capacity) one-hot ever materializes, which is
-what lets grok-1-scale train steps lower (1M tokens × 327k capacity would
-not). Compiled FLOPs reflect *active* experts (honest MoE rooflines).
+Covers grok-1 (8 experts, top-2, renormalised gates) and deepseek-v2-lite
+(64 routed top-6 + 2 shared, softmax gates used as they are:
+``norm_topk_prob=False``). Dispatch is **dropless** sort-and-segment: the
+n·k (token, slot) assignments are sorted by expert, each expert's
+contiguous segment runs through its weights as one grouped matmul
+(``jax.lax.ragged_dot``), and the outputs are unsorted back by the
+inverse permutation and summed with the gates. Every assignment is
+computed, whatever the batch's load per expert, so a token's expert
+output never depends on its batch-mates (a capacity buffer would drop
+the overflow of a busy expert). Memory is O(n·k·(d + d_ff)); compiled
+FLOPs are the active experts' (honest MoE rooflines).
 
 The router aux (load-balance) loss follows Shazeer et al.: E · Σ_e f_e·p_e.
 """
@@ -17,6 +21,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import phases
 from repro.models.layers import init_mlp, mlp_fwd, truncated_normal
 
 
@@ -38,61 +43,57 @@ def init_moe(key, d_model: int, d_ff: int, num_experts: int,
     return p
 
 
-def _route(p, xt, top_k: int):
+def _route(p, xt, top_k: int, norm_topk_prob: bool):
+    """Softmax router → (probs (n, E), gates (n, k), experts (n, k)). The
+    gates are the top-k probabilities, renormalised to sum to 1 only
+    with ``norm_topk_prob``."""
     logits = xt.astype(jnp.float32) @ p["router"]           # (n, E)
     probs = jax.nn.softmax(logits, -1)
     gate_vals, expert_idx = jax.lax.top_k(probs, top_k)     # (n, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    if norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
     return probs, gate_vals, expert_idx
 
 
-def _dispatch_gather(p, xt, gate_vals, expert_idx, capacity: int):
-    """Scatter/gather expert execution. xt (n, d) → (n, d)."""
+def _experts(p, xt, gate_vals, expert_idx):
+    """Dropless routed-expert FFN. xt (n, d) → (n, d)."""
     n, d = xt.shape
     E = p["router"].shape[1]
     k = expert_idx.shape[1]
-
-    onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.int32)    # (n, k, E)
-    flat = onehot.reshape(n * k, E)
-    pos_all = (jnp.cumsum(flat, 0) - flat).reshape(n, k, E)
-    pos = (pos_all * onehot).sum(-1)                           # (n, k)
-    keep = pos < capacity
-
-    # scatter token ids into (E, capacity) slots; overflow rows drop
-    tok_ids = jnp.broadcast_to(jnp.arange(n)[:, None], (n, k))
-    e_flat = expert_idx.reshape(-1)
-    p_flat = jnp.where(keep, pos, capacity).reshape(-1)
-    slot_tok = jnp.full((E, capacity + 1), n, jnp.int32)
-    slot_tok = slot_tok.at[e_flat, p_flat].set(
-        tok_ids.reshape(-1), mode="drop")[:, :capacity]
-
-    xt_pad = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)], 0)
-    buf = xt_pad[slot_tok]                                     # (E, cap, d)
-
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, p["experts_gate"]))
-    h = h * jnp.einsum("ecd,edf->ecf", buf, p["experts_up"])
-    out_buf = jnp.einsum("ecf,efd->ecd", h, p["experts_down"])  # (E, cap, d)
-
-    # gather per (token, slot) and combine with gates
-    y = out_buf[expert_idx, jnp.where(keep, pos, 0)]            # (n, k, d)
-    y = y * keep[..., None].astype(y.dtype)
-    return jnp.einsum("nkd,nk->nd", y, gate_vals.astype(y.dtype))
+    with jax.named_scope(phases.MOE_ROUTE):
+        flat = expert_idx.reshape(-1)                          # (n·k,)
+        order = jnp.argsort(flat, stable=True)                 # by expert
+        inverse = jnp.argsort(order)
+        sizes = jnp.sum(flat[None, :] == jnp.arange(E)[:, None], axis=1,
+                        dtype=jnp.int32)                       # (E,)
+    with jax.named_scope(phases.MOE_EXPERTS):
+        xs = xt[order // k]                                    # (n·k, d)
+        gate = jax.lax.ragged_dot(xs, p["experts_gate"], sizes)
+        up = jax.lax.ragged_dot(xs, p["experts_up"], sizes)
+        h = jax.nn.silu(gate) * up
+        ys = jax.lax.ragged_dot(h, p["experts_down"], sizes)  # (n·k, d)
+        y = ys[inverse].reshape(n, k, d).astype(jnp.float32)
+        return jnp.einsum("nkd,nk->nd", y, gate_vals)
 
 
-def moe_fwd(p: dict, x: jax.Array, top_k: int,
-            capacity_factor: float = 1.25) -> Tuple[jax.Array, jax.Array]:
-    """x: (b, s, d) → (out, aux_loss). Tokens over capacity are dropped
-    (residual stream carries them — standard Switch behaviour)."""
+def _shared(p, x):
+    with jax.named_scope(phases.MOE_SHARED):
+        return mlp_fwd(p["shared"], x)
+
+
+def moe_fwd(p: dict, x: jax.Array, top_k: int, norm_topk_prob: bool = True
+            ) -> Tuple[jax.Array, jax.Array]:
+    """x: (b, s, d) → (out, aux_loss). Dropless: every token reaches all
+    of its top-k experts."""
     b, s, d = x.shape
     E = p["router"].shape[1]
-    n = b * s
-    xt = x.reshape(n, d)
-    probs, gate_vals, expert_idx = _route(p, xt, top_k)
-    capacity = max(int(capacity_factor * n * top_k / E), 4)
-    out = _dispatch_gather(p, xt, gate_vals, expert_idx, capacity)
-    out = out.reshape(b, s, d)
+    xt = x.reshape(b * s, d)
+    with jax.named_scope(phases.MOE_ROUTE):
+        probs, gate_vals, expert_idx = _route(p, xt, top_k, norm_topk_prob)
+    out = _experts(p, xt, gate_vals, expert_idx).reshape(b, s, d)
     if "shared" in p:
-        out = out + mlp_fwd(p["shared"], x).astype(out.dtype)
+        out = out + _shared(p, x).astype(out.dtype)
 
     # load-balance aux loss
     density = jax.nn.one_hot(expert_idx[:, 0], E).mean(0)
@@ -101,16 +102,13 @@ def moe_fwd(p: dict, x: jax.Array, top_k: int,
     return out.astype(x.dtype), aux
 
 
-def moe_decode(p: dict, x_t: jax.Array, top_k: int) -> jax.Array:
-    """Decode path: same dispatch with a generous capacity factor (small n
-    quantizes capacity harshly; experts run dense weights — never gathered
-    per token, which matters at grok scale: 2×6144×32768 weights/token
-    would be ~300 GB of gather traffic at batch 128)."""
-    b, d = x_t.shape
-    E = p["router"].shape[1]
-    _, gate_vals, expert_idx = _route(p, x_t, top_k)
-    capacity = max(int(2.0 * b * top_k / E), 4)
-    out = _dispatch_gather(p, x_t, gate_vals, expert_idx, capacity)
+def moe_decode(p: dict, x_t: jax.Array, top_k: int,
+               norm_topk_prob: bool = True) -> jax.Array:
+    """Decode path: ``moe_fwd``'s dispatch over the b decoded tokens,
+    (b, d) → (b, d): the grouped matmuls hold one row per (token, slot)."""
+    with jax.named_scope(phases.MOE_ROUTE):
+        _, gate_vals, expert_idx = _route(p, x_t, top_k, norm_topk_prob)
+    out = _experts(p, x_t, gate_vals, expert_idx)
     if "shared" in p:
-        out = out + mlp_fwd(p["shared"], x_t).astype(out.dtype)
+        out = out + _shared(p, x_t).astype(out.dtype)
     return out.astype(x_t.dtype)

@@ -6,7 +6,8 @@ stage, stacked over the stage's repeats, with a per-LayerDef cache kind:
   attn + ParisKV      → core.cache.LayerKVCache (full store + metadata)
   attn sliding-window → (k, v) ring buffers of the window size
   cross (vlm/whisper) → (k_media, v_media), static after prefill
-  mla                 → models.mla.MLACache (latent + metadata)
+  mla                 → core.cache.LayerKVCache with v None: one latent
+                        row per token (G = 1), key and value alike
   ssm                 → models.ssm.SSMCache (O(1) recurrent state)
   hybrid              → {"kv": LayerKVCache, "ssm": SSMCache}
 
@@ -39,11 +40,13 @@ sharing** (ISSUE 7): ``admit_fill(fill_start=...)`` starts a fill past a
 prefix the serving engine mapped from its pool-level prefix index,
 deriving the slot's histograms from the shared blocks' metadata
 (``bucket_hist_from_paged_meta``). ``share_support_reason`` gates the
-feature to all-ParisKV-attention architectures — sliding-window layers
-keep slot-local ring buffers a mapped prefix cannot populate.
+feature to architectures whose every layer is ParisKV attention or MLA —
+sliding-window layers keep slot-local ring buffers a mapped prefix
+cannot populate.
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -103,8 +106,9 @@ def _layer_cache_spec(cfg: ModelConfig, ld: LayerDef, batch: int, n_max: int,
         g, hd = cfg.num_kv_heads, cfg.head_dim
         out["media_kv"] = (mk((batch, t, g, hd), dt), mk((batch, t, g, hd), dt))
     elif ld.mixer == "mla":
-        out["kv"] = (MLA.mla_cache_spec(batch, n_max, cfg, dt) if as_spec
-                     else MLA.init_mla_cache(batch, n_max, cfg, dt))
+        make = CC.cache_spec if as_spec else CC.init_layer_cache
+        out["kv"] = make(batch, n_max, 1, cfg.retrieval_dim(), pcfg, dt,
+                         values=False)
     elif ld.mixer == "ssm":
         out["ssm"] = (SSM.ssm_cache_spec(batch, cfg, dt) if as_spec
                       else SSM.init_ssm_cache(batch, cfg, dt))
@@ -200,8 +204,12 @@ def make_paged_caches(cfg: ModelConfig, batch: int, num_blocks: int,
     block pool (one PagedLayerKVCache per attn/hybrid-attn layer, stacked
     over the stage repeat). Bounded-size state — sliding-window ring
     buffers, SSM recurrent state, media K/V — stays slot-local (batch,
-    ...) because it neither fragments nor grows with context. MLA latent
-    caches are not paged yet (ROADMAP; raises UnsupportedPagedConfig).
+    ...) because it neither fragments nor grows with context. An MLA
+    layer pages its latent: a pool with one (num_blocks, block_size, 1,
+    r + dr) latent leaf and no ``v`` (the latent is key and value), its
+    metadata and ``hist`` at G = 1 over the latent's subspaces. The
+    tiered pool (``num_device_blocks``) does not take it
+    (``offload_support_reason``).
 
     Every paged ParisKV layer also carries ``hist``: the slot-local
     (batch, G, B, 2^m) int32 incremental bucket histogram the fused
@@ -225,7 +233,11 @@ def make_paged_caches(cfg: ModelConfig, batch: int, num_blocks: int,
     pcfg = cfg.pariskv
     dt = _dtype(cfg)
 
-    def paged_kv():
+    def paged_kv(ld):
+        if ld.mixer == "mla":
+            make = CC.paged_cache_spec if as_spec else CC.init_paged_cache
+            return make(num_blocks, block_size, 1, cfg.retrieval_dim(), pcfg,
+                        dt, values=False)
         if num_device_blocks is not None:
             if as_spec:
                 return CC.tiered_cache_spec(num_blocks, num_device_blocks,
@@ -241,9 +253,10 @@ def make_paged_caches(cfg: ModelConfig, batch: int, num_blocks: int,
         return CC.init_paged_cache(num_blocks, block_size, cfg.num_kv_heads,
                                    cfg.head_dim, pcfg, dt)
 
-    def hist():
-        shape = (batch, cfg.num_kv_heads, pcfg.num_subspaces(cfg.head_dim),
-                 pcfg.num_centroids())
+    def hist(ld):
+        g, hd = ((1, cfg.retrieval_dim()) if ld.mixer == "mla"
+                 else (cfg.num_kv_heads, cfg.head_dim))
+        shape = (batch, g, pcfg.num_subspaces(hd), pcfg.num_centroids())
         if as_spec:
             return jax.ShapeDtypeStruct(shape, jnp.int32)
         return jnp.zeros(shape, jnp.int32)
@@ -265,15 +278,12 @@ def make_paged_caches(cfg: ModelConfig, batch: int, num_blocks: int,
     for si, stage in enumerate(layer_plan(cfg)):
         stage_cache = {}
         for i, ld in enumerate(stage.layers):
-            if ld.mixer == "mla":
+            if ld.mixer == "mla" and num_device_blocks is not None:
                 raise UnsupportedPagedConfig(
-                    cfg, si, i, ld.mixer,
-                    "MLA latent caches stay contiguous (ROADMAP); serve "
-                    "this config with the slot engine (ServingEngine) or "
-                    "an attention-mixer config")
+                    cfg, si, i, ld.mixer, offload_support_reason(cfg))
             entry = _layer_cache_spec(cfg, ld, batch, n_max, as_spec)
-            if ld.mixer in ("attn", "hybrid") and ld.use_pariskv:
-                entry = {**entry, "kv": paged_kv(), "hist": hist()}
+            if ld.mixer in ("attn", "hybrid", "mla") and ld.use_pariskv:
+                entry = {**entry, "kv": paged_kv(ld), "hist": hist(ld)}
                 if num_device_blocks is not None:
                     entry["fetch"] = fetch_stats()
             stage_cache[f"l{i}"] = _stack_spec(entry, stage.repeat, as_spec)
@@ -336,10 +346,10 @@ def _layer_prefill(p, x, ld: LayerDef, cfg: ModelConfig, positions, media,
             cache = {**cache,
                      "kv": _ring_prefill(cache["kv"], k_new, v_new, lengths)}
     elif ld.mixer == "mla":
-        y = MLA.mla_train(p["attn"], h, cfg, positions)
-        mc = MLA.mla_prefill_cache(p["attn"], h, cache["kv"], cfg, positions,
-                                   signs)
-        cache = {**cache, "kv": mc}
+        y, lat = MLA.mla_prefill(p["attn"], h, cfg, positions)
+        kvc, _ = CC.prefill_write(cache["kv"], lat[:, :, None], None, pcfg,
+                                  signs, lengths=lengths)
+        cache = {**cache, "kv": kvc}
     elif ld.mixer == "cross":
         y = jnp.tanh(p["cross_gate"]) * L.attn_cross(p["attn"], h, media, ld.attn)
         g, hd = cfg.num_kv_heads, cfg.head_dim
@@ -373,7 +383,8 @@ def _layer_prefill(p, x, ld: LayerDef, cfg: ModelConfig, positions, media,
     if ld.ffn != "none":
         h = L.rms_norm(x, p["norm_mlp"], cfg.norm_eps)
         if ld.ffn == "moe":
-            y, _ = MOE.moe_fwd(p["moe"], h, cfg.experts_per_token)
+            y, _ = MOE.moe_fwd(p["moe"], h, cfg.experts_per_token,
+                               cfg.norm_topk_prob)
         else:
             y = L.mlp_fwd(p["mlp"], h)
         x = x + y.astype(x.dtype)
@@ -445,7 +456,9 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
     histogram from the ``hist`` cache entry) unless ``paged_fused`` is
     False. ``hist`` is maintained at promotion on *both* paged paths, so
     the flag can flip between runs without invalidating state; its cost
-    shows under the ``pariskv.promote`` scope (core.phases).
+    shows under the ``pariskv.promote`` scope (core.phases). An MLA
+    layer's latent pool always takes the fused path
+    (``mla.mla_decode_paged``).
 
     ``dev_map`` switches paged ParisKV layers to the **tiered** pool
     (ISSUE 6): retrieval runs unchanged over the device-resident
@@ -543,15 +556,15 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
             y, kv = L.attn_decode_dense(p["attn"], h, cache["kv"], pos, ld.attn)
             cache = {**cache, "kv": kv}
     elif ld.mixer == "mla":
-        y, mc = MLA.mla_decode(p["attn"], h, cache["kv"], regions, cfg, signs,
-                               num_candidates)
-        with jax.named_scope(phases.PROMOTE):
-            mc = jax.lax.cond(
-                jnp.any(promote_mask),
-                lambda c: MLA.mla_promote_rows(c, regions.enc_end,
-                                               promote_mask, pcfg, signs),
-                lambda c: c, mc)
-        cache = {**cache, "kv": mc}
+        if isinstance(cache["kv"], CC.PagedLayerKVCache):
+            y, kvc = MLA.mla_decode_paged(
+                p["attn"], h, cache["kv"], cache["hist"], block_tables,
+                regions, cfg, signs, num_candidates)
+        else:
+            y, kvc = MLA.mla_decode(p["attn"], h, cache["kv"], regions, cfg,
+                                    signs, num_candidates,
+                                    use_pariskv=ld.use_pariskv)
+        cache = {**cache, **promote_and_store(kvc)}
     elif ld.mixer == "cross":
         km, vm = cache["media_kv"]
         q = (h @ p["attn"]["wq"]).reshape(h.shape[0], ld.attn.num_heads,
@@ -585,11 +598,16 @@ def _layer_decode(p, x_t, ld: LayerDef, cfg: ModelConfig, cache, regions,
         with jax.named_scope(phases.DENSE):
             h = L.rms_norm(x_t[:, None], p["norm_mlp"], cfg.norm_eps)[:, 0]
             if ld.ffn == "moe":
-                y = MOE.moe_decode(p["moe"], h, cfg.experts_per_token)
+                y = MOE.moe_decode(p["moe"], h, cfg.experts_per_token,
+                                   cfg.norm_topk_prob)
             else:
                 y = L.mlp_fwd(p["mlp"], h)
             x_t = x_t + y.astype(x_t.dtype)
     return x_t, cache
+
+
+# prefix rows an MLA fill chunk scores at once (mla.mla_fill_chunk)
+MLA_FILL_BLOCK = 2048
 
 
 class FillCtx(NamedTuple):
@@ -618,11 +636,13 @@ def _layer_fill(p, x_f, ld: LayerDef, cfg: ModelConfig, cache, fctx: FillCtx,
     ring buffer, or block pool), K/V + ParisKV metadata scattered into the
     filling row, and — on the paged path — the slot's incremental bucket
     histogram advanced so it stays exact *mid-fill*, not just at
-    completion. Only attention mixers support chunked fill
-    (``fill_supported``); SSM/MLA/cross prompts still prefill solo."""
-    if ld.mixer != "attn":
+    completion. Attention and MLA mixers support chunked fill
+    (``fill_supported``); an MLA chunk attends in absorbed form to the
+    latent prefix (``mla.mla_fill_chunk``) and writes latent rows the
+    way attention writes K. SSM/cross prompts still prefill solo."""
+    if ld.mixer not in ("attn", "mla"):
         raise NotImplementedError(
-            f"chunked prefill supports attention mixers only, got "
+            f"chunked prefill supports attention and MLA mixers only, got "
             f"{ld.mixer!r} (use prefill_budget=0)")
     pcfg = cfg.pariskv
     P = fctx.q_pos.shape[1]
@@ -635,7 +655,25 @@ def _layer_fill(p, x_f, ld: LayerDef, cfg: ModelConfig, cache, fctx: FillCtx,
     kv = cache["kv"]
     fill_fetched = fill_stall = fill_calls = None
     fill_retries = fill_timeouts = fill_deg = fill_keep = None
-    if isinstance(kv, CC.PagedLayerKVCache):
+    if ld.mixer == "mla":
+        if isinstance(kv, CC.PagedLayerKVCache):
+            n_log = fctx.bt_row.shape[0] * CC.paged_block_size(kv)
+            kb = math.gcd(n_log, MLA_FILL_BLOCK)
+
+            def read(j):
+                lidx = (j * kb + jnp.arange(kb))[None]
+                return CC.paged_gather_rows(kv.k, fctx.bt_row[None],
+                                            lidx)[0, :, 0]
+        else:
+            k_row = row1(kv.k)[0, :, 0]                  # (n_max, r + dr)
+            kb = math.gcd(k_row.shape[0], MLA_FILL_BLOCK)
+
+            def read(j):
+                return jax.lax.dynamic_slice_in_dim(k_row, j * kb, kb)
+        y, lat = MLA.mla_fill_chunk(p["attn"], h, cfg, fctx.q_pos, new_pos,
+                                    fctx.start, read, kb)
+        k_new = v_new = lat[:, :, None]         # a latent pool holds no v
+    elif isinstance(kv, CC.PagedLayerKVCache):
         bs = CC.paged_block_size(kv)
         nblk = fctx.bt_row.shape[0]
         idx = jnp.arange(nblk * bs)[None]
@@ -698,17 +736,18 @@ def _layer_fill(p, x_f, ld: LayerDef, cfg: ModelConfig, cache, fctx: FillCtx,
         p_s = last - (last - jnp.arange(w)) % w  # latest pos < start ≡ s
         pref_pos = jnp.where(p_s >= 0, p_s, -1)[None]
 
-    if isinstance(dist, ShardedPagedDist) and isinstance(
-            kv, CC.PagedLayerKVCache):
-        # prefix k/v came from the shard-local pool; k_new/v_new come back
-        # head-local too, so the block writes below stay shard-local
-        y, k_new, v_new = L.attn_fill_chunk_sharded(
-            p["attn"], h, ld.attn, fctx.q_pos, k_pref, v_pref, pref_pos,
-            new_pos, dist.axis_name)
-    else:
-        y, k_new, v_new = L.attn_fill_chunk(p["attn"], h, ld.attn,
-                                            fctx.q_pos, k_pref, v_pref,
-                                            pref_pos, new_pos)
+    if ld.mixer == "attn":
+        if isinstance(dist, ShardedPagedDist) and isinstance(
+                kv, CC.PagedLayerKVCache):
+            # prefix k/v came from the shard-local pool; k_new/v_new come
+            # back head-local too, so the block writes below stay local
+            y, k_new, v_new = L.attn_fill_chunk_sharded(
+                p["attn"], h, ld.attn, fctx.q_pos, k_pref, v_pref, pref_pos,
+                new_pos, dist.axis_name)
+        else:
+            y, k_new, v_new = L.attn_fill_chunk(p["attn"], h, ld.attn,
+                                                fctx.q_pos, k_pref, v_pref,
+                                                pref_pos, new_pos)
 
     if isinstance(kv, (CC.PagedLayerKVCache, CC.LayerKVCache)):
         meta = None
@@ -762,7 +801,8 @@ def _layer_fill(p, x_f, ld: LayerDef, cfg: ModelConfig, cache, fctx: FillCtx,
     if ld.ffn != "none":
         h = L.rms_norm(x_f, p["norm_mlp"], cfg.norm_eps)
         if ld.ffn == "moe":
-            y, _ = MOE.moe_fwd(p["moe"], h, cfg.experts_per_token)
+            y, _ = MOE.moe_fwd(p["moe"], h, cfg.experts_per_token,
+                               cfg.norm_topk_prob)
         else:
             y = L.mlp_fwd(p["mlp"], h)
         x_f = x_f + y.astype(x_f.dtype)
@@ -779,10 +819,10 @@ def fill_support_reason(cfg: ModelConfig) -> Optional[str]:
                 f"K/V in one encoder pass, so prompts prefill solo")
     for si, stage in enumerate(layer_plan(cfg)):
         for i, ld in enumerate(stage.layers):
-            if ld.mixer != "attn":
+            if ld.mixer not in ("attn", "mla"):
                 return (f"config {name!r}: stage {si} layer {i} mixer "
                         f"{ld.mixer!r} has no chunk-resumable prefill "
-                        f"(attention mixers only)")
+                        f"(attention and MLA mixers only)")
             if ld.cross:
                 return (f"config {name!r}: stage {si} layer {i} has a "
                         f"cross-attention sublayer, which reads "
@@ -792,9 +832,9 @@ def fill_support_reason(cfg: ModelConfig) -> Optional[str]:
 
 def fill_supported(cfg: ModelConfig) -> bool:
     """Whether chunked prefill can serve this architecture: every mixer is
-    plain attention (ParisKV or sliding-window) with no cross sublayer.
-    SSM/hybrid recurrences, MLA latent caches, and media cross-attention
-    still prefill solo (ROADMAP). See ``fill_support_reason`` for *why* a
+    plain attention (ParisKV or sliding-window) or MLA, with no cross
+    sublayer. SSM/hybrid recurrences and media cross-attention still
+    prefill solo (ROADMAP). See ``fill_support_reason`` for *why* a
     config falls back."""
     return fill_support_reason(cfg) is None
 
@@ -829,18 +869,17 @@ def share_supported(cfg: ModelConfig) -> bool:
 
 def offload_support_reason(cfg: ModelConfig) -> Optional[str]:
     """Why the tiered host-offloaded pool canNOT serve this architecture,
-    or None when it can. The tiered pool pages exactly what
-    ``make_paged_caches`` pages — ParisKV attention K/V — so the only
-    extra requirement over paged serving is chunked-prefill support (the
-    offloaded engine admits prompts through the host tier, which needs
-    the chunk-resumable fill path for its prefix reads)."""
+    or None when it can. The tiered pool splits ParisKV attention K/V
+    between a device staging pool and the host; its host pool, staging
+    and fetch paths hold K and V leaves, not the paged latent pool of an
+    MLA layer."""
     name = getattr(cfg, "name", cfg.family)
     for si, stage in enumerate(layer_plan(cfg)):
         for i, ld in enumerate(stage.layers):
             if ld.mixer == "mla":
                 return (f"config {name!r}: stage {si} layer {i} mixer "
-                        f"'mla' keeps latent caches contiguous "
-                        f"(UnsupportedPagedConfig; ROADMAP)")
+                        f"'mla' pages a latent pool, which the host tier "
+                        f"does not handle yet (ROADMAP)")
     return None
 
 
@@ -853,15 +892,15 @@ def sharded_support_reason(cfg: ModelConfig) -> Optional[str]:
     None when it can (ISSUE 8). The mesh partitions the paged ParisKV
     pool on the KV-head axis; bounded slot-local state (ring buffers,
     SSM, media K/V) is replicated and its compute runs identically on
-    every shard, so the only structural blocker is a cache the paged
-    pool itself cannot hold."""
+    every shard. An MLA latent pool has one shared head, so that axis
+    cannot split it."""
     name = getattr(cfg, "name", cfg.family)
     for si, stage in enumerate(layer_plan(cfg)):
         for i, ld in enumerate(stage.layers):
             if ld.mixer == "mla":
                 return (f"config {name!r}: stage {si} layer {i} mixer "
-                        f"'mla' keeps latent caches contiguous — the mesh "
-                        f"shards the paged block pool only (ROADMAP)")
+                        f"'mla' pages one latent head, which the mesh's "
+                        f"head partition does not handle yet (ROADMAP)")
     return None
 
 
@@ -1308,8 +1347,6 @@ def _cache_n_max(cfg: ModelConfig, caches) -> int:
                 kv = lc["kv"]
                 if isinstance(kv, CC.LayerKVCache):
                     return kv.k.shape[2]  # (repeat, b, n, G, hd) stacked
-                if isinstance(kv, MLA.MLACache):
-                    return kv.latent.shape[2]
     return 0
 
 

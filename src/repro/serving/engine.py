@@ -101,10 +101,10 @@ resident/offloaded; ``benchmarks/bench_continuous_batching.py``'s
 ``benchmarks/bench_continuous_batching.py``. Its timing is wave-level by
 construction and documented as such.
 
-Deferred (ROADMAP · Open items): chunked prefill for SSM/MLA/cross
-mixers (attention-only architectures today), paged MLA latent caches,
-non-greedy sampling, and cross-run prefix persistence (the prefix index
-only retains blocks some live request still holds).
+Deferred (ROADMAP · Reach): chunked prefill for SSM/cross mixers
+(attention and MLA architectures today), the host tier and the mesh for
+MLA latent pools, non-greedy sampling, and cross-run prefix persistence
+(the prefix index only retains blocks some live request still holds).
 """
 from __future__ import annotations
 
@@ -261,7 +261,7 @@ class ServingEngine:
     ``prefill_budget=P``: chunked prefill fused into the decode chunk —
     admission only copies the prompt to the device; the scan consumes P
     prompt tokens per mixed step. Token-identical to the solo path
-    (tests/test_chunked_prefill.py); attention-mixer architectures only
+    (tests/test_chunked_prefill.py); attention and MLA architectures
     (``models.serve.fill_supported``).
     """
 
@@ -650,8 +650,8 @@ class PagedServingEngine(ServingEngine):
         along with its incremental-histogram rows — including mid-fill
         eviction via ``cancel()``.
 
-    ``share_prefixes=True`` (requires ``prefill_budget > 0``; ParisKV-
-    attention-only architectures — ``models.serve.share_supported``) adds
+    ``share_prefixes=True`` (requires ``prefill_budget > 0``; ParisKV
+    attention and MLA architectures — ``models.serve.share_supported``) adds
     **block-granular prefix sharing** (ISSUE 7): completed prompt blocks
     register in a chained-content-hash ``prefix_index``, later
     admissions map matching blocks straight into their table (refcount++,

@@ -117,7 +117,7 @@ def test_chunked_unsupported_arch_raises():
     cfg = configs.smoke("mamba2-780m")
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     assert not SV.fill_supported(cfg)
-    with pytest.raises(ValueError, match="attention mixers only"):
+    with pytest.raises(ValueError, match="attention and MLA mixers only"):
         ServingEngine(cfg, params, n_max=128, max_batch=1, prefill_budget=8)
 
 

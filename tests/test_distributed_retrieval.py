@@ -100,14 +100,13 @@ def test_mla_decode_matches_train_logits():
     positions = jnp.broadcast_to(jnp.arange(S), (b, S))
     y_train = MLA.mla_train(p, x, cfg, positions)
 
-    mc = MLA.init_mla_cache(b, 128, cfg, jnp.float32)
-    mc = MLA.mla_prefill_cache(p, x[:, :S - 1], mc, cfg, positions[:, :S - 1],
-                               jnp.asarray(srht.rademacher_signs(
-                                   cfg.pariskv.padded_dim(cfg.retrieval_dim()),
-                                   cfg.pariskv.srht_seed)))
-    regions = CC.CacheRegions(pos=jnp.int32(S - 2), enc_end=jnp.int32(0))
     signs = jnp.asarray(srht.rademacher_signs(
         cfg.pariskv.padded_dim(cfg.retrieval_dim()), cfg.pariskv.srht_seed))
+    mc = CC.init_layer_cache(b, 128, 1, cfg.retrieval_dim(), cfg.pariskv,
+                             jnp.float32, values=False)
+    _, lat = MLA.mla_prefill(p, x[:, :S - 1], cfg, positions[:, :S - 1])
+    mc, _ = CC.prefill_write(mc, lat[:, :, None], None, cfg.pariskv, signs)
+    regions = CC.CacheRegions(pos=jnp.int32(S - 2), enc_end=jnp.int32(0))
     # dense decode (use_pariskv=False): must match the train row exactly
     y_dec, _ = MLA.mla_decode(p, x[:, S - 1], mc, regions, cfg, signs,
                               num_candidates=64, use_pariskv=False)

@@ -77,12 +77,12 @@ def test_moe_routing_respects_topk_and_gates():
     cfg = configs.smoke("grok-1-314b")
     p = MOE.init_moe(jax.random.PRNGKey(0), 64, 128, 4, 0, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 64))
-    out, aux = MOE.moe_fwd(p, x, top_k=2, capacity_factor=4.0)
+    out, aux = MOE.moe_fwd(p, x, top_k=2)
     assert out.shape == x.shape
     assert np.isfinite(np.asarray(out)).all()
     assert float(aux) > 0.5  # E·Σ f·p ≥ 1 at uniform routing
 
-    # with capacity ≥ n·k/E·slack nothing drops: moe equals per-token math
+    # dropless: moe equals per-token math
     xt = x.reshape(-1, 64)
     logits = xt @ p["router"]
     probs = jax.nn.softmax(logits, -1)
@@ -103,17 +103,64 @@ def test_moe_decode_matches_fwd():
     p = MOE.init_moe(jax.random.PRNGKey(2), 32, 64, 4, 1, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(3), (8, 32))
     out_d = MOE.moe_decode(p, x, top_k=2)
-    out_f, _ = MOE.moe_fwd(p, x[:, None], 2, capacity_factor=8.0)
+    out_f, _ = MOE.moe_fwd(p, x[:, None], 2)
     np.testing.assert_allclose(np.asarray(out_d), np.asarray(out_f[:, 0]),
                                rtol=2e-3, atol=2e-3)
 
 
-def test_moe_capacity_drops_overflow():
-    """Force tiny capacity: output must stay finite and bounded."""
-    p = MOE.init_moe(jax.random.PRNGKey(4), 16, 32, 4, 0, jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(5), (1, 64, 16))
-    out, _ = MOE.moe_fwd(p, x, top_k=2, capacity_factor=0.05)
-    assert np.isfinite(np.asarray(out)).all()
+def _moe_per_token(p, xt, top_k, norm_topk_prob):
+    """Each token alone through its top-k experts (and the shared MLP)."""
+    probs = jax.nn.softmax(xt @ p["router"], -1)
+    g, idx = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        g = g / g.sum(-1, keepdims=True)
+    want = np.zeros_like(np.asarray(xt))
+    for i in range(xt.shape[0]):
+        for j in range(top_k):
+            e = int(idx[i, j])
+            h = jax.nn.silu(xt[i] @ p["experts_gate"][e]) * (
+                xt[i] @ p["experts_up"][e])
+            want[i] += float(g[i, j]) * np.asarray(h @ p["experts_down"][e])
+        if "shared" in p:
+            want[i] += np.asarray(
+                jax.nn.silu(xt[i] @ p["shared"]["wi_gate"])
+                * (xt[i] @ p["shared"]["wi_up"]) @ p["shared"]["wo_mlp"])
+    return want, np.asarray(g)
+
+
+@pytest.mark.parametrize("norm_topk_prob", [True, False])
+def test_moe_dropless_when_every_token_picks_one_expert(norm_topk_prob):
+    """Every token of a 64-token batch is routed to experts 0 and 1 (a
+    capacity buffer would drop most of them): each output still equals
+    the token's own expert math, and equals the token served alone."""
+    p = MOE.init_moe(jax.random.PRNGKey(4), 16, 32, 4, 1, jnp.float32)
+    p["router"] = jnp.zeros((16, 4)).at[0, 0].set(50.0).at[0, 1].set(25.0)
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 64, 16)) * 0.1
+    x = x.at[..., 0].set(1.0)
+    out, _ = MOE.moe_fwd(p, x, top_k=2, norm_topk_prob=norm_topk_prob)
+    want, g = _moe_per_token(p, x[0], 2, norm_topk_prob)
+    assert (np.asarray(jax.lax.top_k(x[0] @ p["router"], 2)[1])
+            == [0, 1]).all()
+    np.testing.assert_allclose(np.asarray(out[0]), want, rtol=2e-4,
+                               atol=1e-5)
+    alone = MOE.moe_decode(p, x[0, 5:6], 2, norm_topk_prob=norm_topk_prob)
+    np.testing.assert_allclose(np.asarray(alone[0]), np.asarray(out[0, 5]),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_moe_norm_topk_prob_false_keeps_softmax_gates():
+    """``norm_topk_prob=False`` (DeepSeek-V2): the gates are the top-k
+    softmax probabilities as they are, summing to less than 1."""
+    p = MOE.init_moe(jax.random.PRNGKey(6), 32, 48, 8, 0, jnp.float32)
+    p["router"] = p["router"] * 10.0          # spread the probabilities
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 12, 32))
+    out, _ = MOE.moe_fwd(p, x, top_k=3, norm_topk_prob=False)
+    want, g = _moe_per_token(p, x.reshape(-1, 32), 3, False)
+    assert (g.sum(-1) < 0.999).all()
+    np.testing.assert_allclose(np.asarray(out).reshape(-1, 32), want,
+                               rtol=2e-3, atol=2e-4)
+    renorm, _ = MOE.moe_fwd(p, x, top_k=3, norm_topk_prob=True)
+    assert not np.allclose(np.asarray(renorm), np.asarray(out), atol=1e-4)
 
 
 # ----------------------------------------------------------- optimizer ----

@@ -166,3 +166,36 @@ def test_select_candidates_compiles_without_scatter_or_sort(compile_tpu, arch):
         (shape, jnp.int32), kernel=False)
     # opcodes only: the text also names the test's own function
     assert not re.findall(r"\s(scatter|sort)\(", text)
+
+
+def test_deepseek_cell_chunk_compiles_with_its_scopes(compile_tpu, one_chip):
+    """The ``deepseek-v2-lite-longctx-decode`` cell's decode chunk (5
+    layers of DeepSeek-V2-Lite, 64 experts, a 65536-token latent pool of
+    512 blocks, chunked fill of 512) compiles for one v5e with the
+    Stage-I/II kernels in it, fits the chip, and names its MoE and latent
+    ops under ``model.dense``."""
+    import dataclasses
+    from repro.core import phases
+    from repro.models import model as M
+    from repro.serving import PagedServingEngine
+    cfg = dataclasses.replace(configs.get("deepseek-v2-lite-16b"),
+                              num_layers=5, tie_embeddings=False)
+    params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = PagedServingEngine(cfg, params, n_max=65536, max_batch=1,
+                             block_size=128, chunk_size=8,
+                             prefill_budget=512)
+    state = jax.eval_shape(eng._init_state)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    bt = jax.ShapeDtypeStruct(eng._bt.shape, jnp.int32, sharding=one_chip)
+    compiled = eng._chunk.lower(on_chip(params), on_chip(state), bt).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for sub in phases.SUB:
+        assert any(f"{phases.DENSE}/{sub}/" in n for n in names), sub
