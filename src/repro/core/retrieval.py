@@ -20,10 +20,11 @@ identical semantics, validated against these functions.
 A crucial implementation point (matches the paper's "bucket-level" design):
 the tier weight is a property of the *centroid bucket*, not of the key — all
 keys assigned to the same centroid share its proxy score ⟨q_b, c⟩, so we (1)
-histogram keys over the 2^m buckets, (2) rank the ≤256 buckets by proxy
-score, (3) convert each bucket's cumulative key-count position into a tier
-weight, and (4) look the weight up per key. Cost: O(2^m log 2^m + n) instead
-of O(n log n).
+histogram keys over the 2^m buckets, (2) count for each bucket the keys in
+the buckets that outrank it by proxy score — one compare-and-reduce over
+the ≤256 × 256 bucket pairs, already in bucket-id order, (3) convert that
+position into a tier weight, and (4) look the weight up per key. Cost:
+O(2^m · 2^m + n) per (query head, subspace) instead of O(n log n).
 
 On the fused paged path the per-step histogram in (1) is never recomputed
 — it lives as per-(slot, G, B) cache state with exactly four writers
@@ -103,25 +104,33 @@ def tier_weight_table(cent_scores: jax.Array, counts: jax.Array,
                  query-head dims in cent_scores)
     n_valid:     (...,) number of indexable keys
     → (..., B, 2^m) int32 weights in {0, 1, .., 6}.
+
+    Buckets rank by descending score, ties to the lower bucket id (the
+    order of a stable ``argsort(-cent_scores)``; -0.0 and 0.0 tie). A
+    bucket's position is the number of keys in the buckets ranked above
+    it, counted in bucket-id order by one compare-and-reduce over every
+    pair of a row's buckets: no sort, gather or scatter, which a TPU runs
+    an element at a time. The cost is O(rows · 2^m · 2^m), fine for the
+    2^m ≤ 256 buckets ``centroids.codebook`` keeps.
     """
-    counts = jnp.broadcast_to(counts, cent_scores.shape)
-    order = jnp.argsort(-cent_scores, axis=-1)                     # bucket rank
-    counts_sorted = jnp.take_along_axis(counts, order, axis=-1)
-    csum_inclusive = jnp.cumsum(counts_sorted, axis=-1)
-    csum_exclusive = csum_inclusive - counts_sorted                # keys ranked above bucket
+    nb = cent_scores.shape[-1]
+    # pairs [c', c] with c' on the reduced (sublane) axis, c on the lanes
+    s_other = cent_scores[..., :, None]
+    s_self = cent_scores[..., None, :]
+    lower_id = np.arange(nb)[:, None] < np.arange(nb)[None, :]
+    above = (s_other > s_self) | ((s_other == s_self) & lower_id)
+    keys_above = jnp.sum(jnp.where(above, counts[..., :, None], 0), axis=-2)
 
     # position of the bucket's *first* key as a fraction of the top-ρ budget
     denom = jnp.maximum(cfg.rho * n_valid.astype(jnp.float32), 1.0)
-    pos_frac = csum_exclusive.astype(jnp.float32) / denom[..., None, None]
+    pos_frac = keys_above.astype(jnp.float32) / denom[..., None, None]
 
-    pcts = jnp.asarray(cfg.tier_pcts, jnp.float32)
-    wts = jnp.asarray(cfg.tier_weights + (0,), jnp.int32)          # tier L.. → 0
-    tier = jnp.searchsorted(pcts, pos_frac, side="right")
-    w_sorted = wts[jnp.minimum(tier, len(cfg.tier_weights))]
-
-    # scatter weights back to bucket-id order via the inverse permutation
-    inv = jnp.argsort(order, axis=-1)
-    return jnp.take_along_axis(w_sorted, inv, axis=-1)
+    # weight of tier #{tier_pcts ≤ pos_frac} (tier_pcts ascending), tier L.. → 0
+    wts = cfg.tier_weights + (0,)
+    w = jnp.full(pos_frac.shape, wts[0], jnp.int32)
+    for t, p in enumerate(np.asarray(cfg.tier_pcts, np.float32), 1):
+        w = jnp.where(pos_frac >= p, wts[min(t, len(cfg.tier_weights))], w)
+    return w
 
 
 def collision_scores(meta_ids: jax.Array, q_sub: jax.Array, valid: jax.Array,

@@ -64,6 +64,63 @@ def test_tier_weights_zero_outside_top_rho():
     np.testing.assert_array_equal(np.asarray(tbl[0, 0]), [6, 0, 0, 0])
 
 
+def _tier_table_by_sort(scores, counts, n_valid, cfg):
+    """The tier table by ranking: stable argsort of -scores, the counts'
+    cumsum in rank order, searchsorted, and back by the inverse
+    permutation."""
+    counts = np.broadcast_to(counts, scores.shape)
+    order = np.argsort(-scores, axis=-1, kind="stable")
+    ranked = np.take_along_axis(counts, order, axis=-1)
+    excl = np.cumsum(ranked, axis=-1, dtype=np.int32) - ranked
+    denom = np.maximum(np.float32(cfg.rho) * n_valid.astype(np.float32),
+                       np.float32(1.0))
+    pos_frac = excl.astype(np.float32) / denom[..., None, None]
+    tier = np.searchsorted(np.asarray(cfg.tier_pcts, np.float32), pos_frac,
+                           side="right")
+    wts = np.asarray(cfg.tier_weights + (0,), np.int32)
+    w = wts[np.minimum(tier, len(cfg.tier_weights))]
+    return np.take_along_axis(w, np.argsort(order, axis=-1), axis=-1)
+
+
+def _tier_case(name):
+    """(scores (2, 2, 3, 4, 256), counts, n_valid (2, 1, 1)) for a named
+    case; counts carry the query-head axis unless the case broadcasts it."""
+    rs = np.random.default_rng(sum(map(ord, name)))
+    shape = (2, 2, 3, 4, 256)
+    scores = rs.normal(size=shape).astype(np.float32)
+    counts = rs.integers(0, 60, size=shape).astype(np.int32)
+    if name == "tied_signed_zeros":           # exact ties, -0.0 against 0.0
+        scores = np.round(scores * 4) / 4
+        zero = rs.random(shape) < 0.15
+        scores[zero] = np.where(rs.random(zero.sum()) < 0.5, 0.0, -0.0)
+        scores[..., :2] = [0.0, -0.0]
+        scores[..., 2:4] = [-0.0, 0.0]
+    elif name == "zero_counts":
+        counts[rs.random(shape) < 0.8] = 0
+        counts[0, 1] = 0                       # an empty histogram
+    elif name == "hg_broadcast":               # the paged path's (b, G, 1, B, 2^m)
+        counts = counts[:, :, :1]
+    n_valid = counts[:, :1, :1, 0].sum(-1).astype(np.int32)
+    if name == "empty_region":                 # denom clamps to 1
+        n_valid[:] = 0
+    return scores, counts, n_valid
+
+
+@pytest.mark.parametrize("rho", [0.25, 1.0, 0.1])
+@pytest.mark.parametrize("case", ["random", "tied_signed_zeros", "zero_counts",
+                                  "empty_region", "hg_broadcast"])
+def test_tier_weight_table_equals_ranking_by_sort(case, rho):
+    """Ranking by comparison gives the sorted ranking's table exactly:
+    ties to the lower bucket id, -0.0 tied with 0.0."""
+    cfg = ParisKVConfig(rho=rho)
+    scores, counts, n_valid = _tier_case(case)
+    got = R.tier_weight_table(jnp.asarray(scores), jnp.asarray(counts),
+                              jnp.asarray(n_valid), cfg)
+    want = _tier_table_by_sort(scores, counts, n_valid, cfg)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_collision_scores_range_and_mask():
     n = 1024
     keys = make_keys(0, n)

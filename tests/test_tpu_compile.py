@@ -7,8 +7,9 @@ head_dim 128 → 16 subspaces) and StableLM-1.6B (32 × 1, head_dim 64 → 8
 subspaces), block 128, an 8192-token context, 4 rows. Interpret mode
 hides every one of these rules, so each kernel test asserts that the
 compiled program really contains the kernel (``tpu_custom_call``). The
-candidate selection is plain XLA: its test asserts that the chip's
-program holds no scatter and no sort.
+candidate selection and the tier table are plain XLA: their tests assert
+that the chip's program holds no scatter and no sort (and the tier
+table's no gather).
 
 The topology is described inside a fixture (never at import): only the
 test process that runs this file loads the TPU compiler library.
@@ -166,6 +167,28 @@ def test_select_candidates_compiles_without_scatter_or_sort(compile_tpu, arch):
         (shape, jnp.int32), kernel=False)
     # opcodes only: the text also names the test's own function
     assert not re.findall(r"\s(scatter|sort)\(", text)
+
+
+# the benchmark cells' (b, G, Hg, B, 2^m) tier tables; counts at Hg 1
+TIER_SHAPES = {"qwen2-1.5b": (1, 2, 6, 16, 256),
+               "stablelm-1.6b": (4, 32, 1, 8, 256),
+               "deepseek-v2-lite-16b": (1, 1, 16, 128, 256)}
+
+
+@pytest.mark.parametrize("arch", TIER_SHAPES)
+def test_tier_weight_table_compiles_without_gather_sort_or_scatter(
+        compile_tpu, arch):
+    """The tier table is plain XLA on the chip and ranks the buckets by
+    comparison: no sort, and no gather or scatter through a permutation
+    (the TPU runs those an element at a time)."""
+    from repro.core.retrieval import tier_weight_table
+    pcfg = configs.get(arch).pariskv
+    shape = TIER_SHAPES[arch]
+    text = compile_tpu(
+        lambda cs, counts, n: tier_weight_table(cs, counts, n, pcfg),
+        (shape, jnp.float32), (shape[:2] + (1,) + shape[3:], jnp.int32),
+        (shape[:1] + (1, 1), jnp.int32), kernel=False)
+    assert not re.findall(r"\s(gather|sort|scatter)\(", text)
 
 
 def test_deepseek_cell_chunk_compiles_with_its_scopes(compile_tpu, one_chip):
